@@ -90,4 +90,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

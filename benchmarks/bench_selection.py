@@ -118,4 +118,7 @@ def main(argv: list[str] | None = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -165,6 +165,9 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     r = run()
     for k, v in r.items():
         print(f"  {k}: {v}")

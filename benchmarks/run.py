@@ -4,6 +4,8 @@
 
 Prints one CSV block per benchmark, prefixed with `== <name> ==`, plus a
 `name,us_per_call,derived` summary line per benchmark (harness timing).
+A benchmark that raises is printed as an `ERROR` row and the run exits
+nonzero once every benchmark has had its turn.
 """
 
 from __future__ import annotations
@@ -48,7 +50,7 @@ def main() -> None:
          else bench_kernels3d.run),
         ("roofline_from_dryrun", bench_roofline.run),
     ]
-    summary = []
+    summary, failed = [], []
     for name, fn in benches:
         print(f"== {name} ==", flush=True)
         t0 = time.perf_counter()
@@ -59,6 +61,7 @@ def main() -> None:
             derived = len(rows) - 1
         except Exception as e:  # noqa: BLE001
             print(f"ERROR,{type(e).__name__},{e}")
+            failed.append(name)
             derived = -1
         dt = (time.perf_counter() - t0) * 1e6
         summary.append(f"{name},{dt:.0f},{derived}")
@@ -66,7 +69,12 @@ def main() -> None:
     print("== summary (name,us_per_call,derived) ==")
     for s in summary:
         print(s)
+    if failed:
+        raise SystemExit(f"{len(failed)} benchmark(s) raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -978,27 +978,35 @@ class CheckpointManager:
         manifest, layout = self._load_manifest(d)
         if layout == "segments":
             return step, self._restore_v2(d, manifest)
-        out: dict[str, np.ndarray] = {}
         with open(os.path.join(d, "data.bin"), "rb") as f:
             blob = f.read()
-        for fl in manifest["fields"]:
+
+        def decode(fl: dict) -> np.ndarray:
             seg = blob[fl["offset"] : fl["offset"] + fl["nbytes"]]
             shape, dtype = tuple(fl["shape"]), np.dtype(fl["dtype"])
             if fl["codec"] == "none":
                 # exact original-dtype bytes (non-float / policy-raw rows)
-                arr = codecs.writeable_frombuffer(seg, dtype).reshape(shape)
-            elif fl["codec"] == "raw":
+                return codecs.writeable_frombuffer(seg, dtype).reshape(shape)
+            if fl["codec"] == "raw":
                 # selection-era raw rows hold f32 working-dtype bytes
-                arr = (
+                return (
                     codecs.writeable_frombuffer(seg, np.float32)
                     .reshape(shape)
                     .astype(dtype)
                 )
-            else:
-                cf = sel.CompressedField(fl["codec"], seg, shape, fl["dtype"])
-                arr = sel.decompress(cf)
-            out[fl["name"]] = arr
-        return step, out
+            cf = sel.CompressedField(fl["codec"], seg, shape, fl["dtype"])
+            return sel.decompress(cf)
+
+        # the host decoders spend their time in numpy, which releases the
+        # GIL: decode on the save path's pool width, largest fields first
+        fields = sorted(manifest["fields"], key=lambda fl: -fl["nbytes"])
+        if self.cfg.workers > 1 and len(fields) > 1:
+            with ThreadPoolExecutor(max_workers=self.cfg.workers) as ex:
+                arrs = list(ex.map(decode, fields))
+        else:
+            arrs = [decode(fl) for fl in fields]
+        by_name = {fl["name"]: arr for fl, arr in zip(fields, arrs)}
+        return step, {fl["name"]: by_name[fl["name"]] for fl in manifest["fields"]}
 
     def _restore_v2(
         self, d: str, manifest: dict,

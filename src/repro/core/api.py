@@ -487,17 +487,22 @@ def decompress_pytree(ct: CompressedTree) -> Any:
     DESIGN.md §6."""
     from . import sharded as _sh
 
-    leaves = []
-    for name, cf in ct.fields.items():
+    def decode(cf) -> np.ndarray:
         if isinstance(cf, ShardedCompressedField):
             view = _sh.decode_segments(cf.view_shape, cf.segments)
-            arr = view.reshape(cf.shape).astype(np.dtype(cf.dtype))
-        else:
-            # `decompress` handles both raw conventions: selection-less raw
-            # leaves restore exact original-dtype bytes, everything else
-            # decodes through the codec registry (always writeable)
-            arr = decompress(cf)
-        leaves.append(arr)
+            return view.reshape(cf.shape).astype(np.dtype(cf.dtype))
+        # `decompress` handles both raw conventions: selection-less raw
+        # leaves restore exact original-dtype bytes, everything else
+        # decodes through the codec registry (always writeable)
+        return decompress(cf)
+
+    fields = list(ct.fields.values())
+    if len(fields) > 1:
+        # the host decoders spend their time in numpy, which releases the GIL
+        with ThreadPoolExecutor(max_workers=_default_workers()) as ex:
+            leaves = list(ex.map(decode, fields))
+    else:
+        leaves = [decode(cf) for cf in fields]
     return jax.tree_util.tree_unflatten(ct.treedef, leaves)
 
 

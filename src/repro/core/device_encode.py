@@ -89,13 +89,15 @@ def _degree_order(nd: int) -> np.ndarray:
 
 @jax.jit
 def _sz_pass1(x, eb):
-    """Quantize + Lorenzo (Pallas tier for 2-D/3-D) -> residuals, symbols,
-    histogram, and the |x| max for the float32-exactness guard."""
+    """Quantize + Lorenzo (Pallas tier for 2-D/3-D) -> residuals, symbols
+    (flat: pass 2 works on the stream order, and handing it a tiled n-D
+    array makes its TPU compile several times slower), histogram, and the
+    |x| max for the float32-exactness guard."""
     d = ops.lorenzo_encode(x, eb)
     syms = jnp.where(
         jnp.abs(d) > _sz.RESIDUAL_RADIUS, 0, d + _sz.RESIDUAL_RADIUS + 1
-    ).astype(jnp.int32)
-    hist = jnp.bincount(syms.reshape(-1), length=N_SYMBOLS)
+    ).astype(jnp.int32).reshape(-1)
+    hist = jnp.bincount(syms, length=N_SYMBOLS)
     amax = jnp.max(jnp.abs(x))
     return d, syms, hist, amax
 
@@ -109,7 +111,6 @@ def _sz_pass2(syms, d, lut_codes, lut_lens, *, n_words, esc_cap, window):
     window of codes. Escapes compact by rank through `searchsorted` on the
     escape-count prefix sum — `esc_cap` gathers instead of a full-length
     scatter."""
-    syms = syms.reshape(-1)
     lens = lut_lens[syms]
     codes = lut_codes[syms]
     offsets = jnp.cumsum(lens) - lens  # exclusive

@@ -67,11 +67,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.runtime import dist
 from repro.runtime import sharding as rsh
 
@@ -95,11 +90,9 @@ from .transforms import block_transform_nd, bot_linf_gain, bot_matrix
 
 
 def _smap(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_rep was renamed check_vma)."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - depends on jax version
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 # ---------------------------------------------------------------------------

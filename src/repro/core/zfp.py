@@ -83,23 +83,44 @@ def zfp_stats(x: jax.Array, eb: jax.Array | float, transform: str = "zfp") -> ZF
 # ---------------------------------------------------------------------------
 
 
+#: blocks per float64 pass of the host coder: bounds its temporaries to a
+#: few hundred MB however large the field (every block is independent)
+_CHUNK_BLOCKS = 1 << 18
+
+
+def _chunks(nblk: int):
+    return ((lo, min(lo + _CHUNK_BLOCKS, nblk)) for lo in range(0, nblk, _CHUNK_BLOCKS))
+
+
+def _transform_blocks(blocks: np.ndarray, T: np.ndarray) -> np.ndarray:
+    """T along every block axis of (nblk, 4, ..., 4) float64 blocks."""
+    for axis in range(1, blocks.ndim):
+        blocks = np.moveaxis(np.tensordot(blocks, T, axes=[[axis], [1]]), -1, axis)
+    return blocks
+
+
+def _plane_step(e: np.ndarray, eb: float, gain_n: float) -> np.ndarray:
+    raw = eb / (np.exp2(e.astype(np.float64)) * gain_n)
+    return np.exp2(np.floor(np.log2(np.maximum(raw, 2.0**-60))))
+
+
 def _prepare_blocks(x: np.ndarray, eb: float, transform: str):
     n = x.ndim
     T = bot_matrix(transform)  # float64
     gain_n = bot_linf_gain(transform) ** n
     blocks, padded = blockize(jnp.asarray(x, jnp.float32))
-    blocks = np.asarray(blocks, dtype=np.float64)
-    mx = np.maximum(np.abs(blocks).reshape(blocks.shape[0], -1).max(axis=1), 1e-30)
-    e = np.ceil(np.log2(mx)).astype(np.int16)
-    norm = blocks * np.exp2(-e.astype(np.float64)).reshape((-1,) + (1,) * n)
-    coeffs = norm
-    for axis in range(1, n + 1):
-        coeffs = np.moveaxis(np.tensordot(coeffs, T, axes=[[axis], [1]]), -1, axis)
-    raw = eb / (np.exp2(e.astype(np.float64)) * gain_n)
-    pexp = np.floor(np.log2(np.maximum(raw, 2.0**-60)))
-    step = np.exp2(pexp)
-    q = np.trunc(coeffs.reshape(coeffs.shape[0], -1) / step[:, None]).astype(np.int64)
-    return q, e, step, padded, gain_n, T
+    blocks = np.asarray(blocks)
+    nblk = blocks.shape[0]
+    e = np.empty(nblk, np.int16)
+    q = np.empty((nblk, 4**n), np.int64)
+    for lo, hi in _chunks(nblk):
+        b = blocks[lo:hi].astype(np.float64)
+        mx = np.maximum(np.abs(b).reshape(hi - lo, -1).max(axis=1), 1e-30)
+        e[lo:hi] = np.ceil(np.log2(mx))
+        norm = b * np.exp2(-e[lo:hi].astype(np.float64)).reshape((-1,) + (1,) * n)
+        coeffs = _transform_blocks(norm, T).reshape(hi - lo, -1)
+        q[lo:hi] = np.trunc(coeffs / _plane_step(e[lo:hi], eb, gain_n)[:, None])
+    return q, e, _plane_step(e, eb, gain_n), padded, gain_n, T
 
 
 def _degree_order(nd: int) -> np.ndarray:
@@ -116,88 +137,103 @@ def _k_width(bsz: int) -> int:
     return int(np.ceil(np.log2(bsz + 1)))
 
 
+def _rank_dtype(bsz: int):
+    """Smallest integer type that holds a 1-based rank (and k) in a block."""
+    return np.int8 if bsz < 127 else np.int16
+
+
+def _active_rows(nsb: np.ndarray, p: int) -> np.ndarray | None:
+    """Indices of the blocks still coded at plane p, or None when all are
+    (the common case: whole arrays then stand in for row gathers)."""
+    active = nsb > p
+    return None if active.all() else np.flatnonzero(active)
+
+
 def _emit_planes(m: np.ndarray, neg: np.ndarray, nsb: np.ndarray) -> list[np.ndarray]:
     """Plane-major, degree-ordered k-prefix significance coding.
 
     Per plane & block: refinement bits of significant coeffs; a fixed-width
     k = 1 + rank of the last newly-significant remaining coefficient (0 if
     none); significance bits of the first k remaining coefficients only;
-    signs of the newly significant. Vectorized over all blocks (m must
-    already be in degree order).
+    signs of the newly significant. Vectorized over the blocks still coded
+    at each plane (m must already be in degree order).
     """
     parts: list[np.ndarray] = []
     nblk, bsz = m.shape
     w = _k_width(bsz)
-    kshift = np.arange(w - 1, -1, -1, dtype=np.int64)
+    rdt = _rank_dtype(bsz)
+    kshift = np.arange(w - 1, -1, -1, dtype=rdt)
     maxp = int(nsb.max()) if nsb.size else 0
     for p in range(maxp - 1, -1, -1):
-        active = nsb > p
-        if not active.any():
-            continue
-        act = active[:, None]
-        sig_prev = (m >> (p + 1)) > 0
-        bit_p = ((m >> p) & 1).astype(np.uint8)
+        rows = _active_rows(nsb, p)
+        mp, ng = (m, neg) if rows is None else (m[rows], neg[rows])
+        sig_prev = (mp >> (p + 1)) != 0
+        bit_p = ((mp >> p) & 1).astype(bool)
         # 1) refinement bits of already-significant coefficients
-        parts.append(bit_p[act & sig_prev])
-        # 2) k per active block with remaining coeffs (fixed width w)
-        rem = act & ~sig_prev
-        has_rem = rem.any(axis=1) & active
-        rank = np.cumsum(rem, axis=1) - 1  # rank among remaining, valid on rem
-        newly = rem & (bit_p == 1)
-        k = np.max(np.where(newly, rank + 1, 0), axis=1)  # (nblk,)
+        parts.append(bit_p[sig_prev].view(np.uint8))
+        # 2) k per block with remaining coeffs (fixed width w)
+        rem = ~sig_prev
+        rank = np.cumsum(rem, axis=1, dtype=rdt)  # 1-based rank, valid on rem
+        newly = rem & bit_p
+        k = np.max(np.where(newly, rank, rdt(0)), axis=1)
+        has_rem = rank[:, -1] > 0
         kb = ((k[has_rem, None] >> kshift[None, :]) & 1).astype(np.uint8)
         parts.append(kb.reshape(-1))
         # 3) significance bits of the first k remaining coefficients
-        test = rem & (rank < k[:, None])
-        parts.append(bit_p[test])
+        test = rem & (rank <= k[:, None])
+        parts.append(bit_p[test].view(np.uint8))
         # 4) signs of newly-significant coefficients
-        parts.append(neg[newly].astype(np.uint8))
+        parts.append(ng[newly].view(np.uint8))
     return parts
 
 
 def _read_planes(bits: np.ndarray, pos: int, nblk: int, bsz: int, nsb: np.ndarray):
-    m = np.zeros((nblk, bsz), dtype=np.int64)
-    neg = np.zeros((nblk, bsz), dtype=bool)
-    w = _k_width(bsz)
-    kweights = (1 << np.arange(w - 1, -1, -1)).astype(np.int64)
+    """Inverse of `_emit_planes` over 0/1 `bits` from `pos` -> (magnitudes,
+    signs, end position)."""
     maxp = int(nsb.max()) if nsb.size else 0
+    m = np.zeros((nblk, bsz), dtype=np.int64 if maxp > 30 else np.int32)
+    neg = np.zeros((nblk, bsz), dtype=bool)
+    bits = bits.astype(np.uint8, copy=False)
+    w = _k_width(bsz)
+    rdt = _rank_dtype(bsz)
+    kweights = (1 << np.arange(w - 1, -1, -1)).astype(rdt)
     for p in range(maxp - 1, -1, -1):
-        active = nsb > p
-        if not active.any():
-            continue
-        act = active[:, None]
-        sig_prev = m > 0  # m currently holds bits above plane p
-        m[active] <<= 1
+        rows = _active_rows(nsb, p)
+        mp = m if rows is None else m[rows]
+        sig_prev = mp != 0  # mp holds the bits above plane p
+        mp <<= 1
         # 1) refinement
-        ref_mask = act & sig_prev
-        nref = int(ref_mask.sum())
+        nref = int(np.count_nonzero(sig_prev))
         if nref:
-            m[ref_mask] |= bits[pos : pos + nref]
+            mp[sig_prev] |= bits[pos : pos + nref]
         pos += nref
         # 2) k values
-        rem = act & ~sig_prev
-        has_rem = rem.any(axis=1) & active
-        ngrp = int(has_rem.sum())
-        k = np.zeros(nblk, dtype=np.int64)
+        rem = ~sig_prev
+        rank = np.cumsum(rem, axis=1, dtype=rdt)
+        has_rem = rank[:, -1] > 0
+        ngrp = int(np.count_nonzero(has_rem))
+        k = np.zeros(len(mp), dtype=rdt)
         if ngrp:
-            kb = bits[pos : pos + ngrp * w].reshape(ngrp, w)
+            kb = bits[pos : pos + ngrp * w].reshape(ngrp, w).astype(rdt)
             k[has_rem] = kb @ kweights
         pos += ngrp * w
         # 3) significance bits of the first k remaining coefficients
-        rank = np.cumsum(rem, axis=1) - 1
-        test = rem & (rank < k[:, None])
-        nbm = int(test.sum())
-        newly = np.zeros_like(rem)
+        test = rem & (rank <= k[:, None])
+        nbm = int(np.count_nonzero(test))
+        newly = np.zeros(mp.shape, dtype=bool)
         if nbm:
             bmb = bits[pos : pos + nbm]
-            m[test] |= bmb
-            newly[test] = bmb.astype(bool)
+            mp[test] |= bmb
+            newly[test] = bmb.view(bool)
         pos += nbm
         # 4) signs
-        nnew = int(newly.sum())
+        nnew = int(np.count_nonzero(newly))
+        ng = neg if rows is None else neg[rows]
         if nnew:
-            neg[newly] = bits[pos : pos + nnew].astype(bool)
+            ng[newly] = bits[pos : pos + nnew].view(bool)
         pos += nnew
+        if rows is not None:
+            m[rows], neg[rows] = mp, ng
     return m, neg, pos
 
 
@@ -250,7 +286,10 @@ def zfp_encode_quantized(
     order = _degree_order(n)
     q = q[:, order]  # degree-ordered layout for the k-prefix coder
     m = np.abs(q)
+    if m.size and m.max() < 2**31:
+        m = m.astype(np.int32)  # halves the plane coder's temporaries
     neg = q < 0
+    del q
     mx = m.max(axis=1) if m.size else np.zeros(0, dtype=np.int64)
     nsb = np.zeros(len(m), dtype=np.uint8)
     nz = mx > 0
@@ -285,21 +324,20 @@ def zfp_decompress(buf: bytes) -> np.ndarray:
     off += nblk
     (nbits,) = struct.unpack_from("<Q", buf, off)
     off += 8
-    bits = np.unpackbits(np.frombuffer(buf[off:], dtype=np.uint8))[:nbits].astype(np.int64)
+    bits = np.unpackbits(np.frombuffer(buf[off:], dtype=np.uint8))[:nbits]
     bsz = 4**n
     m, neg, _ = _read_planes(bits, 0, nblk, bsz, nsb.astype(np.int64))
+    del bits
     inv = np.argsort(_degree_order(n))  # undo the degree-ordered layout
-    m = m[:, inv]
-    neg = neg[:, inv]
-    gain_n = bot_linf_gain(transform) ** n
-    raw = eb / (np.exp2(e.astype(np.float64)) * gain_n)
-    step = np.exp2(np.floor(np.log2(np.maximum(raw, 2.0**-60))))
-    mag = np.where(m > 0, (m.astype(np.float64) + 0.5) * step[:, None], 0.0)
-    coeffs = np.where(neg, -mag, mag).reshape((nblk,) + (4,) * n)
-    T = bot_matrix(transform)
-    rec = coeffs
-    for axis in range(1, n + 1):
-        rec = np.moveaxis(np.tensordot(rec, T.T, axes=[[axis], [1]]), -1, axis)
-    rec = rec * np.exp2(e.astype(np.float64)).reshape((-1,) + (1,) * n)
-    out = unblockize(jnp.asarray(rec, jnp.float32), padded, shape)
+    step = _plane_step(e, eb, bot_linf_gain(transform) ** n)
+    Tt = bot_matrix(transform).T
+    rec = np.empty((nblk,) + (4,) * n, np.float32)
+    for lo, hi in _chunks(nblk):
+        mm = m[lo:hi][:, inv]
+        mag = np.where(mm > 0, (mm.astype(np.float64) + 0.5) * step[lo:hi, None], 0.0)
+        coeffs = np.where(neg[lo:hi][:, inv], -mag, mag).reshape((-1,) + (4,) * n)
+        rec[lo:hi] = _transform_blocks(coeffs, Tt) * np.exp2(
+            e[lo:hi].astype(np.float64)
+        ).reshape((-1,) + (1,) * n)
+    out = unblockize(jnp.asarray(rec), padded, shape)
     return np.asarray(out, dtype=np.float32)

@@ -2,21 +2,29 @@
 
 The SZ Stage I+II hot spot (DESIGN.md §3.1, §3.3, §3.4). One pass over
 HBM: round(x / 2eb) and the n-D Lorenzo difference of the integer codes,
-tiled through VMEM. Tile-boundary neighbors are fetched with extra *views*
-of the same input one element back (1-element-granular index maps on
-blocks with size-1 dims), so no halo padding or materialized shifted
-copies are needed. In 2-D that is one row + one column + one corner view;
-in 3-D it is the full lower halo shell — three faces, three edges, and
-one corner over a (bz, bm, bn) grid (DESIGN.md §3.4).
+tiled through VMEM. The residual is one backward difference per axis of
+the integer codes, so a tile needs exactly one neighbour per axis:
+
+  * x (lanes): every tile spans the field's whole last dim, so the left
+    neighbour is in the tile — one lane rotate, with lane 0 (the domain
+    boundary) masked to zero;
+  * y (sublanes): rows are tiled in multiples of 8; the row above a tile
+    is the last row of the aligned 8-row block before it, fetched as its
+    own block with the block index clamped at 0 and masked on the first
+    row tile;
+  * z (3-D, leading dim): z is the innermost, sequential grid axis and the
+    last plane of the previous tile is carried in VMEM scratch; the one
+    row above the tile in that plane arrives as an aligned (1, 8, n) block.
+
+Every block is (8, 128)-aligned or spans its whole array dim, and no
+index map ever points before the array.
 
 TPU mapping notes:
-  * (bm, bn) = (256, 256) default in 2-D — 256 KiB f32 per tile, lane dim
-    a multiple of 128 for clean (8,128) VREG tiling; (8, 128, 256) in 3-D
-    (1 MiB f32 per tile) with the same trailing-dim alignment.
-  * round / sub are VPU element ops; the whole kernel is memory-bound, so
-    fusing quantize+stencil halves HBM traffic vs running them separately.
-  * grid is fully parallel (no carried state — this is the entire point of
-    the prequantized reformulation vs sequential SZ).
+  * the tile holds ~`TILE_BYTES` of f32: rows (and, once a tile holds
+    whole planes, planes) are sized from the row width;
+  * round / sub are VPU element ops and the shifts are XLU rotates; the
+    whole kernel is memory-bound, so fusing quantize+stencil halves HBM
+    traffic vs running them separately.
 """
 
 from __future__ import annotations
@@ -26,217 +34,180 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: f32 bytes of one input tile: double-buffered input and output plus a
+#: few tile-sized temporaries stay well inside v5e's 16 MiB scoped VMEM
+TILE_BYTES = 1 << 20
+#: widest row a tile can hold: one 8-row tile of f32 at `TILE_BYTES`
+MAX_ROW = TILE_BYTES // (4 * 8)
+
+_SMEM_SCALAR = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-DEFAULT_BLOCK = (256, 256)
-DEFAULT_BLOCK3 = (8, 128, 256)
+def _round_up(a: int, b: int) -> int:
+    return -(-a // b) * b
 
 
-def _encode_kernel(eb_ref, x_ref, top_ref, left_ref, corner_ref, out_ref):
+def tile(shape: tuple[int, ...]) -> tuple[int, ...]:
+    """The (bm, n) / (bz, bm, n) launch tile for a field of `shape`: the
+    whole last dim, rows in multiples of 8 split evenly so padding stays
+    under 8 rows per tile, and in 3-D as many planes as fit once a tile
+    holds whole planes."""
+    n = int(shape[-1])
+    m = int(shape[-2])
+    cap = max(8, (TILE_BYTES // (4 * n)) // 8 * 8)
+    bm = _round_up(-(-m // -(-m // cap)), 8)
+    if len(shape) == 2:
+        return (bm, n)
+    z = int(shape[0])
+    bz = max(1, TILE_BYTES // (4 * bm * n)) if bm >= m else 1
+    bz = -(-z // -(-z // bz))
+    return (bz, bm, n)
+
+
+def _prev(a: jax.Array, axis: int, fill) -> jax.Array:
+    """`a` one element back along `axis` (a[i-1] at i), with `fill` at
+    element 0."""
+    rolled = pltpu.roll(a, 1, axis)
+    first = jax.lax.broadcasted_iota(jnp.int32, a.shape, axis) == 0
+    return jnp.where(first, fill, rolled)
+
+
+def _prev_planes(a: jax.Array, first: jax.Array) -> jax.Array:
+    """`a` one plane back along the leading dim, with `first` in plane 0."""
+    if a.shape[0] == 1:
+        return first
+    return jnp.concatenate([first, a[:-1]], axis=0)
+
+
+def _encode2d_kernel(eb_ref, x_ref, up_ref, out_ref):
     i = pl.program_id(0)
-    j = pl.program_id(1)
-    delta = 2.0 * eb_ref[0, 0]
-    k = jnp.round(x_ref[...] / delta)
-    # halo rows/cols are views of the same array one element back; mask the
-    # domain boundary (Lorenzo predicts 0 outside the domain)
-    top = jnp.round(top_ref[...] / delta) * (i > 0)  # (1, bn)
-    left = jnp.round(left_ref[...] / delta) * (j > 0)  # (bm, 1)
-    corner = jnp.round(corner_ref[...] / delta) * ((i > 0) & (j > 0))  # (1,1)
-    k_up = jnp.concatenate([top, k[:-1, :]], axis=0)
-    k_left = jnp.concatenate([left, k[:, :-1]], axis=1)
-    ul_row = jnp.concatenate([corner, top[:, :-1]], axis=1)  # (1, bn)
-    k_ul = jnp.concatenate([ul_row, k_left[:-1, :]], axis=0)
-    d = k - k_up - k_left + k_ul
-    out_ref[...] = d.astype(jnp.int32)
+    delta = 2.0 * eb_ref[0]
+    k = jnp.round(x_ref[...] / delta)  # (bm, n)
+    up = jnp.where(i > 0, jnp.round(up_ref[7:8, :] / delta), 0.0)  # (1, n)
+    ky = k - _prev(k, 0, up)
+    out_ref[...] = (ky - _prev(ky, 1, 0.0)).astype(jnp.int32)
+
+
+def _check(shape, block):
+    assert block[-1] == shape[-1], ("tiles span the whole last dim", shape, block)
+    assert block[-2] % 8 == 0, ("rows tile in multiples of 8", block)
+    assert all(s % b == 0 for s, b in zip(shape, block)), (shape, block)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def lorenzo2d_encode(
-    x: jax.Array,
-    eb: jax.Array | float,
-    block: tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool | None = None,
+    x: jax.Array, eb: jax.Array | float, *, block: tuple[int, int], interpret: bool
 ) -> jax.Array:
     """Fused quantize+Lorenzo for a 2-D f32 field -> int32 residual codes.
 
-    Requires shape divisible by `block` (ops.py pads).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    Requires shape divisible by `block` (ops.py pads)."""
     m, n = x.shape
-    bm, bn = block
-    assert m % bm == 0 and n % bn == 0, (x.shape, block)
-    grid = (m // bm, n // bn)
-    eb_arr = jnp.full((1, 1), eb, jnp.float32)
+    _check(x.shape, block)
+    bm = block[0]
+    r8 = bm // 8
+    eb_arr = jnp.full((1,), eb, jnp.float32)
     return pl.pallas_call(
-        _encode_kernel,
-        grid=grid,
+        _encode2d_kernel,
+        grid=(m // bm,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            # one-row view starting at element row i*bm - 1 (clamped at 0;
-            # the kernel masks i == 0 anyway)
-            pl.BlockSpec((1, bn), lambda i, j: (i * bm - 1, j)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j * bn - 1)),
-            pl.BlockSpec((1, 1), lambda i, j: (i * bm - 1, j * bn - 1)),
+            _SMEM_SCALAR,
+            pl.BlockSpec((bm, n), lambda i: (i, 0)),
+            # the aligned 8-row block ending at row i*bm - 1
+            pl.BlockSpec((8, n), lambda i: (jnp.maximum(i * r8 - 1, 0), 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(eb_arr, x, x, x, x)
+    )(eb_arr, x, x)
 
 
-def _encode3d_kernel(
-    eb_ref, x_ref, zf_ref, yf_ref, xf_ref, zy_ref, zx_ref, yx_ref, c_ref, out_ref
-):
-    """3-D extension of `_encode_kernel` (DESIGN.md §3.4): the lower halo
-    shell of the (bz, bm, bn) tile arrives as seven views of the same
-    input one element back — faces (1,bm,bn)/(bz,1,bn)/(bz,bm,1), edges
-    (1,1,bn)/(1,bm,1)/(bz,1,1) and the (1,1,1) corner. They are assembled
-    into the (bz+1, bm+1, bn+1) extended cube, and the 3-D Lorenzo
-    residual is the composition of one backward difference per axis —
-    exactly `transforms.lorenzo_forward` restricted to the tile."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    g = pl.program_id(2)
-    delta = 2.0 * eb_ref[0, 0]
-
-    def q(ref, keep):
-        # quantize a halo view, masking the domain boundary (Lorenzo
-        # predicts 0 outside the domain)
-        return jnp.round(ref[...] / delta) * keep
-
-    k = jnp.round(x_ref[...] / delta)  # (bz, bm, bn)
-    zf = q(zf_ref, i > 0)  # (1, bm, bn) plane at z-1
-    yf = q(yf_ref, j > 0)  # (bz, 1, bn) plane at y-1
-    xf = q(xf_ref, g > 0)  # (bz, bm, 1) plane at x-1
-    zy = q(zy_ref, (i > 0) & (j > 0))  # (1, 1, bn)
-    zx = q(zx_ref, (i > 0) & (g > 0))  # (1, bm, 1)
-    yx = q(yx_ref, (j > 0) & (g > 0))  # (bz, 1, 1)
-    c = q(c_ref, (i > 0) & (j > 0) & (g > 0))  # (1, 1, 1)
-    # extended cube: plane 0 carries the z-1 halo, row/col 0 of every
-    # plane carry the y-1 / x-1 halos, composed exactly like the shard
-    # engine's dim-by-dim halo extension (core/sharded.py)
-    plane0 = jnp.concatenate(
-        [
-            jnp.concatenate([c, zy], axis=2),  # (1, 1, bn+1)
-            jnp.concatenate([zx, zf], axis=2),  # (1, bm, bn+1)
-        ],
-        axis=1,
-    )
-    body = jnp.concatenate(
-        [
-            jnp.concatenate([yx, yf], axis=2),  # (bz, 1, bn+1)
-            jnp.concatenate([xf, k], axis=2),  # (bz, bm, bn+1)
-        ],
-        axis=1,
-    )
-    d = jnp.concatenate([plane0, body], axis=0)  # (bz+1, bm+1, bn+1)
-    for ax in range(3):
-        d = jax.lax.slice_in_dim(d, 1, d.shape[ax], axis=ax) - jax.lax.slice_in_dim(
-            d, 0, d.shape[ax] - 1, axis=ax
-        )
-    out_ref[...] = d.astype(jnp.int32)
+def _encode3d_kernel(eb_ref, x_ref, up_ref, zup_ref, out_ref, zprev_ref):
+    """3-D extension of `_encode2d_kernel` (DESIGN.md §3.4): a backward
+    difference along z (the previous plane is carried in `zprev_ref`),
+    then y (the row above the tile, itself z-differenced), then x."""
+    j = pl.program_id(0)
+    i = pl.program_id(1)
+    delta = 2.0 * eb_ref[0]
+    k = jnp.round(x_ref[...] / delta)  # (bz, bm, n)
+    zprev = jnp.where(i > 0, zprev_ref[...], 0.0)  # (1, bm, n)
+    zprev_ref[...] = k[-1:]
+    kz = k - _prev_planes(k, zprev)
+    up = jnp.where(j > 0, jnp.round(up_ref[:, 7:8, :] / delta), 0.0)  # (bz, 1, n)
+    zup = jnp.where((i > 0) & (j > 0), jnp.round(zup_ref[:, 7:8, :] / delta), 0.0)
+    upz = up - _prev_planes(up, zup)
+    ky = kz - _prev(kz, 1, upz)
+    out_ref[...] = (ky - _prev(ky, 2, 0.0)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def lorenzo3d_encode(
     x: jax.Array,
     eb: jax.Array | float,
-    block: tuple[int, int, int] = DEFAULT_BLOCK3,
-    interpret: bool | None = None,
+    *,
+    block: tuple[int, int, int],
+    interpret: bool,
 ) -> jax.Array:
     """Fused quantize+Lorenzo for a 3-D f32 field -> int32 residual codes.
 
-    Requires shape divisible by `block` (ops.py pads).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    Requires shape divisible by `block` (ops.py pads)."""
     z, m, n = x.shape
-    bz, bm, bn = block
-    assert z % bz == 0 and m % bm == 0 and n % bn == 0, (x.shape, block)
-    grid = (z // bz, m // bm, n // bn)
-    eb_arr = jnp.full((1, 1), eb, jnp.float32)
-    # each halo view starts one element back along its offset dims (clamped
-    # at 0 by pallas; the kernel masks the boundary programs anyway)
+    _check(x.shape, block)
+    bz, bm, _ = block
+    r8 = bm // 8
+    eb_arr = jnp.full((1,), eb, jnp.float32)
+    # grid (row tile j, z tile i): z innermost, so the carried plane is the
+    # one just above in z
     return pl.pallas_call(
         _encode3d_kernel,
-        grid=grid,
+        grid=(m // bm, z // bz),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, g: (0, 0)),
-            pl.BlockSpec((bz, bm, bn), lambda i, j, g: (i, j, g)),
-            pl.BlockSpec((1, bm, bn), lambda i, j, g: (i * bz - 1, j, g)),
-            pl.BlockSpec((bz, 1, bn), lambda i, j, g: (i, j * bm - 1, g)),
-            pl.BlockSpec((bz, bm, 1), lambda i, j, g: (i, j, g * bn - 1)),
-            pl.BlockSpec((1, 1, bn), lambda i, j, g: (i * bz - 1, j * bm - 1, g)),
-            pl.BlockSpec((1, bm, 1), lambda i, j, g: (i * bz - 1, j, g * bn - 1)),
-            pl.BlockSpec((bz, 1, 1), lambda i, j, g: (i, j * bm - 1, g * bn - 1)),
-            pl.BlockSpec((1, 1, 1), lambda i, j, g: (i * bz - 1, j * bm - 1, g * bn - 1)),
+            _SMEM_SCALAR,
+            pl.BlockSpec((bz, bm, n), lambda j, i: (i, j, 0)),
+            pl.BlockSpec((bz, 8, n), lambda j, i: (i, jnp.maximum(j * r8 - 1, 0), 0)),
+            pl.BlockSpec(
+                (1, 8, n),
+                lambda j, i: (jnp.maximum(i * bz - 1, 0), jnp.maximum(j * r8 - 1, 0), 0),
+            ),
         ],
-        out_specs=pl.BlockSpec((bz, bm, bn), lambda i, j, g: (i, j, g)),
+        out_specs=pl.BlockSpec((bz, bm, n), lambda j, i: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((z, m, n), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, bm, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(eb_arr, x, x, x, x, x, x, x, x)
+    )(eb_arr, x, x, x)
 
 
 def _dequant_kernel(eb_ref, k_ref, out_ref):
-    delta = 2.0 * eb_ref[0, 0]
+    delta = 2.0 * eb_ref[0]
     out_ref[...] = k_ref[...].astype(jnp.float32) * delta
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def dequantize2d(
-    k: jax.Array,
-    eb: jax.Array | float,
-    block: tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool | None = None,
+def dequantize(
+    k: jax.Array, eb: jax.Array | float, *, block: tuple[int, ...], interpret: bool
 ) -> jax.Array:
-    """Reconstruction from integer codes (decode-side Stage II inverse).
+    """Reconstruction from integer codes (decode-side Stage II inverse) for
+    a 2-D or 3-D field, on the encoder's tiles.
 
-    The Lorenzo inverse itself (2-D cumsum) is left to XLA's optimized scan;
-    this kernel fuses only the elementwise dequantize."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    m, n = k.shape
-    bm, bn = block
-    assert m % bm == 0 and n % bn == 0
-    eb_arr = jnp.full((1, 1), eb, jnp.float32)
+    The Lorenzo inverse itself (an n-D cumsum) is left to XLA's optimized
+    scan; this kernel fuses only the elementwise dequantize."""
+    _check(k.shape, block)
+    grid = tuple(s // b for s, b in zip(k.shape, block))
+    index = lambda *ij: ij  # noqa: E731 - block index == grid index
+    eb_arr = jnp.full((1,), eb, jnp.float32)
     return pl.pallas_call(
         _dequant_kernel,
-        grid=(m // bm, n // bn),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(eb_arr, k)
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def dequantize3d(
-    k: jax.Array,
-    eb: jax.Array | float,
-    block: tuple[int, int, int] = DEFAULT_BLOCK3,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """3-D twin of `dequantize2d`: elementwise dequantize of integer codes
-    (the Lorenzo inverse — a 3-D cumsum — stays with XLA's optimized scan)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    z, m, n = k.shape
-    bz, bm, bn = block
-    assert z % bz == 0 and m % bm == 0 and n % bn == 0
-    eb_arr = jnp.full((1, 1), eb, jnp.float32)
-    return pl.pallas_call(
-        _dequant_kernel,
-        grid=(z // bz, m // bm, n // bn),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, g: (0, 0)),
-            pl.BlockSpec((bz, bm, bn), lambda i, j, g: (i, j, g)),
-        ],
-        out_specs=pl.BlockSpec((bz, bm, bn), lambda i, j, g: (i, j, g)),
-        out_shape=jax.ShapeDtypeStruct((z, m, n), jnp.float32),
+        grid=grid,
+        in_specs=[_SMEM_SCALAR, pl.BlockSpec(block, index)],
+        out_specs=pl.BlockSpec(block, index),
+        out_shape=jax.ShapeDtypeStruct(k.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * len(grid)
+        ),
         interpret=interpret,
     )(eb_arr, k)

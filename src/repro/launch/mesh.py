@@ -1,9 +1,18 @@
-"""Production mesh builders. Functions (not module constants) so importing
-never touches jax device state."""
+"""Mesh builders. Functions (not module constants) so importing never
+touches jax device state.
+
+Every mesh has Auto axes: the model's sharding annotations are layout
+constraints for the partitioner to propagate (`jax.make_mesh` defaults to
+Explicit axes, under which `with_sharding_constraint` is an assert)."""
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -11,12 +20,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     ('pod','data','model') for 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh():
     """Single-device mesh for smoke/integration tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
+
+
+def make_device_mesh():
+    """('data', 'model') mesh over the devices that are present, data
+    parallel: (n, 1) — what the train and serve launchers run under."""
+    return _mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def make_emulated_mesh(shape=(2, 4), axes=("data", "model")):
@@ -42,7 +57,7 @@ def make_emulated_mesh(shape=(2, 4), axes=("data", "model")):
             "set XLA_FLAGS=--xla_force_host_platform_device_count=8 before jax "
             "initializes (per process under launch/mhrun.py)"
         )
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def describe_mesh(mesh) -> dict:

@@ -16,7 +16,8 @@ Protocol (shared filesystem, no sockets beyond jax's own coordinator):
   scenario name + args, output dir), and launches `cmd + [spec.json]`
   once per process with `MHRUN_PROCESS_ID=<pid>` in the environment
   (XLA_FLAGS is scrubbed so the parent's emulated-device setting cannot
-  leak into workers — `worker_init` re-derives it from the spec);
+  leak into workers — `worker_init` re-derives it from the spec — and
+  JAX_PLATFORMS=cpu keeps every worker off any accelerator);
 * each worker calls `worker_init(spec_path)` FIRST (before any jax
   device use), runs its scenario, and reports through
   `write_result(...)` -> `result.<pid>.json`; uncaught scenario
@@ -101,8 +102,11 @@ def run(
         env = os.environ.copy()
         # the parent's emulated-device flags must not leak: each worker
         # derives its own --xla_force_host_platform_device_count from the
-        # spec (worker_init), BEFORE its jax backend initializes
+        # spec (worker_init), BEFORE its jax backend initializes. Workers
+        # are CPU processes: on a machine with a chip, the chip stays with
+        # the one process that owns it
         env.pop("XLA_FLAGS", None)
+        env["JAX_PLATFORMS"] = "cpu"
         env["MHRUN_PROCESS_ID"] = str(pid)
         if extra_env:
             env.update(extra_env)

@@ -23,7 +23,8 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.decision_cache import DecisionCache
 from repro.core.policy import serving_policies
-from repro.launch.mesh import make_local_mesh, make_production_mesh
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_device_mesh
 from repro.models import build_model, reduced_for_smoke
 from repro.models import nn as rnn
 from repro.runtime import sharding
@@ -100,6 +101,7 @@ def run_continuous(args, cfg, model, params) -> dict:
 
 
 def main(argv=None) -> dict:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--smoke", action="store_true")
@@ -126,7 +128,7 @@ def main(argv=None) -> dict:
     params = rnn.init_tree(model.desc(), jax.random.key(0))
     if args.continuous:
         return run_continuous(args, cfg, model, params)
-    mesh = make_local_mesh() if len(jax.devices()) == 1 else make_production_mesh()
+    mesh = make_device_mesh()
 
     rng = np.random.default_rng(0)
     b = args.batch

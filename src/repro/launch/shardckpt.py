@@ -29,7 +29,7 @@ byte counts and locality stats:
 import os
 import sys
 
-if "--mh-worker" not in sys.argv:
+if __name__ == "__main__" and "--mh-worker" not in sys.argv:
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()
@@ -45,6 +45,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointConfig, CheckpointManager
 from repro.core import Policy
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import describe_mesh, make_emulated_mesh
 
 
@@ -165,6 +166,7 @@ def _run_multiprocess(args) -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     if "--mh-worker" in sys.argv:
         from repro.launch import mhrun
 
@@ -183,61 +185,80 @@ def main() -> None:
         return
 
     mesh = make_emulated_mesh((2, 4), ("data", "model"))
+    with tempfile.TemporaryDirectory() as d:
+        dryrun(mesh, make_emulated_mesh((4, 2), ("data", "model")),
+               args.fields, args.dim, args.eb_rel, d)
+
+
+def placement(x) -> dict[str, tuple[int, ...]]:
+    """{device: local shard shape} of a jax.Array."""
+    return {str(sh.device): tuple(sh.data.shape) for sh in x.addressable_shards}
+
+
+def dryrun(mesh, mesh2, n_fields: int, dim: int, eb_rel: float, directory: str) -> dict:
+    """Sharded save on `mesh`, elastic restore under `mesh2`, and decision
+    + value parity against the unsharded writer; prints one line per step
+    and raises SystemExit on any mismatch."""
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
-          f"({mesh.devices.size} emulated devices)")
-    tree, _ = synth_state(mesh, args.fields, args.dim)
-
-    with tempfile.TemporaryDirectory() as d_sh, tempfile.TemporaryDirectory() as d_un:
-        msh = CheckpointManager(
-            CheckpointConfig(
-                directory=d_sh,
-                policy=Policy.fixed_accuracy(eb_rel=args.eb_rel),
-                sharded=True,
-            )
+          f"({mesh.devices.size} {mesh.devices.flat[0].platform} devices)")
+    tree, _ = synth_state(mesh, n_fields, dim)
+    d_sh, d_un = os.path.join(directory, "sharded"), os.path.join(directory, "unsharded")
+    w0 = "layer00/w"
+    saved_on = placement(tree["params"][w0])
+    print(f"save placement of params/{w0}: {saved_on}")
+    msh = CheckpointManager(
+        CheckpointConfig(
+            directory=d_sh, policy=Policy.fixed_accuracy(eb_rel=eb_rel), sharded=True
         )
-        t0 = time.perf_counter()
-        path = msh.save(1, tree)
-        t_sh = time.perf_counter() - t0
-        with open(os.path.join(path, "manifest.json")) as f:
-            man = json.load(f)
-        n_segs = sum(len(fl["segments"]) for fl in man["fields"])
-        print(f"sharded save: {t_sh:.2f}s  {man['total_bytes']/1e6:.2f} MB "
-              f"({man['raw_bytes']/max(man['total_bytes'],1):.2f}x) "
-              f"{len(man['fields'])} fields / {n_segs} segments")
+    )
+    t0 = time.perf_counter()
+    path = msh.save(1, tree)
+    t_sh = time.perf_counter() - t0
+    with open(os.path.join(path, "manifest.json")) as f:
+        man = json.load(f)
+    n_segs = sum(len(fl["segments"]) for fl in man["fields"])
+    print(f"sharded save: {t_sh:.2f}s  {man['total_bytes']/1e6:.2f} MB "
+          f"({man['raw_bytes']/max(man['total_bytes'],1):.2f}x) "
+          f"{len(man['fields'])} fields / {n_segs} segments")
 
-        host_tree = jax.tree_util.tree_map(np.asarray, tree)
-        mun = CheckpointManager(
-            CheckpointConfig(directory=d_un, policy=Policy.fixed_accuracy(eb_rel=args.eb_rel))
-        )
-        t0 = time.perf_counter()
-        mun.save(1, host_tree)
-        t_un = time.perf_counter() - t0
-        print(f"gather-then-compress save: {t_un:.2f}s "
-              f"(shard-local is {t_un / max(t_sh, 1e-9):.2f}x)")
+    host_tree = jax.tree_util.tree_map(np.asarray, tree)
+    mun = CheckpointManager(
+        CheckpointConfig(directory=d_un, policy=Policy.fixed_accuracy(eb_rel=eb_rel))
+    )
+    t0 = time.perf_counter()
+    mun.save(1, host_tree)
+    t_un = time.perf_counter() - t0
+    print(f"gather-then-compress save: {t_un:.2f}s "
+          f"(shard-local is {t_un / max(t_sh, 1e-9):.2f}x)")
 
-        # elastic restore: consume the 2x4 checkpoint under a 4x2 mesh
-        mesh2 = make_emulated_mesh((4, 2), ("data", "model"))
-        _, shardings2 = synth_state(mesh2, args.fields, args.dim)
-        t0 = time.perf_counter()
-        _, restored = msh.restore_tree(tree, shardings=shardings2)
-        t_rs = time.perf_counter() - t0
-        w0 = "layer00/w"
-        ok_spec = restored["params"][w0].sharding.mesh.devices.shape == (4, 2)
-        print(f"elastic restore onto 4x2 mesh: {t_rs:.2f}s resharded={ok_spec}")
+    # elastic restore: consume the checkpoint under the second mesh
+    _, shardings2 = synth_state(mesh2, n_fields, dim)
+    t0 = time.perf_counter()
+    _, restored = msh.restore_tree(tree, shardings=shardings2)
+    t_rs = time.perf_counter() - t0
+    ok_spec = restored["params"][w0].sharding.mesh.devices.shape == mesh2.devices.shape
+    restored_on = placement(restored["params"][w0])
+    print(f"elastic restore onto {dict(zip(mesh2.axis_names, mesh2.devices.shape))} "
+          f"mesh: {t_rs:.2f}s resharded={ok_spec}; placement of params/{w0}: "
+          f"{restored_on}")
 
-        # decision + value parity against the unsharded writer
-        _, f_sh = msh.restore()
-        _, f_un = mun.restore()
-        mism = [k for k in f_un if not np.array_equal(f_un[k], f_sh[k])]
-        bits_sh = man["selection_bits"]
-        with open(os.path.join(d_un, f"step_{1:09d}", "manifest.json")) as f:
-            bits_un = json.load(f)["selection_bits"]
-        flips = [k for k in bits_un if bits_un[k] != bits_sh.get(k)]
-        print(f"parity: {len(mism)} value mismatches, {len(flips)} decision flips "
-              f"across {len(f_un)} fields")
-        if mism or flips:
-            raise SystemExit(f"PARITY FAILURE: {mism[:3]} {flips[:3]}")
+    # decision + value parity against the unsharded writer
+    _, f_sh = msh.restore()
+    _, f_un = mun.restore()
+    mism = [k for k in f_un if not np.array_equal(f_un[k], f_sh[k])]
+    bits_sh = man["selection_bits"]
+    with open(os.path.join(d_un, f"step_{1:09d}", "manifest.json")) as f:
+        bits_un = json.load(f)["selection_bits"]
+    flips = [k for k in bits_un if bits_un[k] != bits_sh.get(k)]
+    print(f"parity: {len(mism)} value mismatches, {len(flips)} decision flips "
+          f"across {len(f_un)} fields")
+    if mism or flips or not ok_spec:
+        raise SystemExit(f"PARITY FAILURE: {mism[:3]} {flips[:3]} resharded={ok_spec}")
     print("dryrun OK")
+    return dict(
+        save_s=t_sh, unsharded_save_s=t_un, restore_s=t_rs, fields=len(f_un),
+        saved_on=saved_on, restored_on=restored_on,
+    )
 
 
 if __name__ == "__main__":

@@ -23,7 +23,8 @@ from repro.checkpoint import CheckpointConfig, CheckpointManager
 from repro.configs import get_config
 from repro.core import Policy, PolicySet
 from repro.data import DataConfig, synthetic_batch
-from repro.launch.mesh import make_local_mesh, make_production_mesh
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_device_mesh
 from repro.models import build_model, reduced_for_smoke
 from repro.models import nn as rnn
 from repro.optim import AdamWConfig, GradCompressConfig
@@ -32,6 +33,7 @@ from repro.runtime.steps import init_opt_state, make_train_step
 
 
 def main(argv=None) -> dict:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--steps", type=int, default=100)
@@ -66,8 +68,7 @@ def main(argv=None) -> dict:
         cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
     model = build_model(cfg)
 
-    n_dev = len(jax.devices())
-    mesh = make_local_mesh() if n_dev == 1 else make_production_mesh()
+    mesh = make_device_mesh()
     opt_cfg = AdamWConfig(lr=args.lr, total_steps=args.steps, warmup_steps=min(20, args.steps // 5))
     gc_cfg = GradCompressConfig() if args.compress_grads else None
     dcfg = DataConfig(vocab=cfg.vocab, seq_len=args.seq, global_batch=args.batch)

@@ -18,7 +18,7 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 from repro.models import nn
 
@@ -232,8 +232,14 @@ def shard_data(x: Any, device) -> np.ndarray:
 
 @contextlib.contextmanager
 def activate(mesh: Mesh, rules: dict):
-    """Bind the activation-constraint hook used by nn.shard()."""
+    """Bind the activation-constraint hook used by nn.shard().
 
+    The constraints are layout hints for the partitioner, so they run on
+    an Auto-typed view of `mesh`: under Explicit axes (the default of
+    `jax.make_mesh`) `with_sharding_constraint` is an assert instead."""
+    mesh = Mesh(
+        mesh.devices, mesh.axis_names, axis_types=(AxisType.Auto,) * len(mesh.axis_names)
+    )
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
 
     def _divisible(dim: int, part) -> bool:
@@ -252,9 +258,7 @@ def activate(mesh: Mesh, rules: dict):
 
     nn.set_shard_fn(shard_fn)
     try:
-        # jax >= 0.5 spells the mesh context jax.set_mesh; on older jax the
-        # Mesh object itself is the context manager
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             yield
     finally:
         nn.set_shard_fn(None)

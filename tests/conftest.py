@@ -21,6 +21,8 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
+# the suite runs on CPU (kernels interpreted) and never claims a chip
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # benchmarks.common is the canonical ATM/Hurricane-like field generator the
 # golden suite freezes; make it importable when pytest is launched from

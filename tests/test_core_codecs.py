@@ -67,6 +67,57 @@ def test_zfp_error_bound_and_roundtrip(shape, kind, eb_rel):
     assert np.abs(x - rec).max() <= _tol(eb, x)
 
 
+def _reference_planes(m, neg, nsb) -> list[int]:
+    """Plane by plane, block by block: the plain definition of the ZFP
+    plane-sectioned stream (refinement bits, k fields, significance bits,
+    signs — each section over every block still coded at the plane)."""
+    nblk, bsz = m.shape
+    w = int(np.ceil(np.log2(bsz + 1)))
+    out = []
+    for p in range(int(nsb.max()) - 1, -1, -1):
+        ref, ks, sig, signs = [], [], [], []
+        for b in range(nblk):
+            if nsb[b] <= p:
+                continue
+            rem = []
+            for c in range(bsz):
+                v = int(m[b, c])
+                if v >> (p + 1):
+                    ref.append((v >> p) & 1)
+                else:
+                    rem.append(c)
+            if not rem:
+                continue
+            newly = [i for i, c in enumerate(rem) if (int(m[b, c]) >> p) & 1]
+            k = newly[-1] + 1 if newly else 0
+            ks.extend((k >> (w - 1 - j)) & 1 for j in range(w))
+            sig.extend((int(m[b, rem[i]]) >> p) & 1 for i in range(k))
+            signs.extend(int(neg[b, rem[i]]) for i in newly)
+        out += ref + ks + sig + signs
+    return out
+
+
+@pytest.mark.parametrize("nd", [1, 2, 3])
+def test_zfp_planes_match_reference_coder(nd):
+    """The vectorized plane coder emits and reads exactly the stream of the
+    plain loop — with blocks of every depth, all-zero blocks among them, so
+    fewer blocks are coded at the top planes than at the bottom ones."""
+    from repro.core import zfp
+
+    rng = np.random.default_rng(nd)
+    bsz, nblk = 4**nd, 60
+    nsb = rng.integers(0, 12, nblk).astype(np.uint8)
+    mag = rng.integers(0, 1 << 12, (nblk, bsz)) >> (12 - nsb.astype(np.int64))[:, None]
+    mag[np.arange(nblk), rng.integers(0, bsz, nblk)] |= (1 << nsb.astype(np.int64)) >> 1
+    neg = (rng.random((nblk, bsz)) < 0.5) & (mag > 0)
+    bits = np.concatenate(zfp._emit_planes(mag, neg, nsb))
+    assert bits.tolist() == _reference_planes(mag, neg, nsb)
+    m, sgn, end = zfp._read_planes(bits, 0, nblk, bsz, nsb)
+    assert end == len(bits)
+    np.testing.assert_array_equal(m, mag)
+    np.testing.assert_array_equal(sgn, neg)
+
+
 def test_bounds_hold_fixed_seeds():
     """Deterministic twin of the hypothesis property test (which lives in
     test_property_codecs.py behind pytest.importorskip)."""
@@ -128,6 +179,49 @@ def test_huffman_roundtrip():
     # rate is within 10% of entropy + table
     h = ent.entropy_bits(freqs)
     assert 8 * len(buf) / len(syms) <= h * 1.1 + 1.0
+
+
+def _reference_encode(syms, table) -> bytes:
+    """Code by code, bit by bit: the plain definition of the stream."""
+    bits = []
+    for s in syms:
+        ln, code = int(table.lens[s]), int(table.codes[s])
+        bits.extend((code >> (ln - 1 - i)) & 1 for i in range(ln))
+    return np.packbits(np.asarray(bits, np.uint8)).tobytes() if bits else b""
+
+
+def _reference_decode(buf, table, count) -> np.ndarray:
+    """Prefix match one code at a time."""
+    by_code = {
+        (int(table.lens[s]), int(table.codes[s])): s for s in np.nonzero(table.lens)[0]
+    }
+    bits = np.unpackbits(np.frombuffer(buf, np.uint8))
+    out, pos = [], 0
+    while len(out) < count:
+        code, ln = 0, 0
+        while (ln, code) not in by_code:
+            code, ln, pos = (code << 1) | int(bits[pos]), ln + 1, pos + 1
+        out.append(by_code[(ln, code)])
+    return np.asarray(out, np.int64)
+
+
+@pytest.mark.parametrize(
+    "n, scale, chunk",
+    [(1, 1.0, None), (5000, 3.0, None), (20000, 300.0, None), (20000, 30.0, 1000)],
+)
+def test_huffman_matches_reference_coder(monkeypatch, n, scale, chunk):
+    """The vectorized, chunked coder emits and reads exactly the stream of
+    the code-by-code definition — including across chunk boundaries."""
+    if chunk is not None:
+        monkeypatch.setattr(ent, "_ENCODE_CHUNK", chunk)
+        monkeypatch.setattr(ent, "_DECODE_CHUNK", 8 * chunk)
+    rng = np.random.default_rng(n)
+    syms = np.clip(np.round(rng.standard_normal(n) * scale) + 2000, 0, 4000).astype(np.int64)
+    table = ent.build_table(np.bincount(syms, minlength=4001))
+    buf = ent.encode(syms, table)
+    assert buf == _reference_encode(syms, table)
+    np.testing.assert_array_equal(ent.decode(buf, table, n), _reference_decode(buf, table, n))
+    np.testing.assert_array_equal(ent.decode(buf, table, n), syms)
 
 
 def test_huffman_degenerate_single_symbol():

@@ -9,7 +9,7 @@ import pytest
 
 from repro.core import embedded
 from repro.core.transforms import block_transform_nd, bot_linf_gain, bot_matrix
-from repro.kernels import bot4, lorenzo, ops, ref
+from repro.kernels import ops, ref
 
 
 def _field(shape, seed, kind="walk"):
@@ -23,7 +23,8 @@ def _field(shape, seed, kind="walk"):
 
 #: tile multiples, clamped-tile shapes, and ragged padded-edge shapes
 SHAPES3 = [(16, 128, 256), (32, 96, 96), (13, 50, 67), (8, 130, 259)]
-BLOCKS3 = [(8, 128, 256), (8, 32, 128), (4, 16, 128)]
+#: legal tiles span the whole last dim; rows are multiples of 8
+BLOCKS3 = [(8, 128, 256), (8, 32, 256), (4, 16, 256)]
 
 
 @pytest.mark.parametrize("shape", SHAPES3)
@@ -40,7 +41,7 @@ def test_lorenzo3d_kernel_matches_ref(shape, kind):
 def test_lorenzo3d_kernel_block_sweep(block):
     x = _field((16, 128, 256), 1)
     eb = 1e-3 * float(jnp.max(x) - jnp.min(x))
-    got = lorenzo.lorenzo3d_encode(x, eb, block=block)
+    got = ops.lorenzo_encode(x, eb, block=block)
     want = ref.lorenzo3d_encode_ref(x, eb)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -161,9 +162,9 @@ def test_fold_plans_keep_3d_fields_3d():
 def test_kernels3d_are_jittable_and_lowerable():
     """The 3-D kernels must lower+compile under jit (TPU-target health)."""
     x = jax.ShapeDtypeStruct((16, 128, 256), jnp.float32)
-    c1 = jax.jit(lambda a: lorenzo.lorenzo3d_encode(a, 1e-3)).lower(x).compile()
+    c1 = jax.jit(lambda a: ops.lorenzo_encode(a, 1e-3)).lower(x).compile()
     assert c1.cost_analysis() is not None
-    c2 = jax.jit(lambda a: bot4.bot3d_fused(a, 1e-3)).lower(x).compile()
+    c2 = jax.jit(lambda a: ops.bot_fused(a, 1e-3)).lower(x).compile()
     assert c2 is not None
 
 
