@@ -40,6 +40,7 @@ compressed fields + the selection-bit stream, exactly the paper's
 
 from __future__ import annotations
 
+import itertools
 import os
 import warnings
 from concurrent.futures import ThreadPoolExecutor
@@ -48,6 +49,7 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import controller as _controller
 from .policy import (
@@ -129,8 +131,19 @@ class CompressedTree:
         return self.raw_nbytes / max(self.nbytes, 1)
 
 
+#: numbers the `compress_pytree` / `decompress_pytree` calls of this process;
+#: each call's spans carry its number as `request`, so the per-field spans on
+#: the pool threads can be matched to the call they serve
+_requests = itertools.count(1)
+
+
 def _leaf_name(path) -> str:
     return "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
+
+
+def _leaf_bytes(leaves: list) -> int:
+    """Bytes of the array leaves of a flattened tree (scalars count 0)."""
+    return sum(getattr(leaf, "nbytes", 0) for _, leaf in leaves)
 
 
 def _default_workers() -> int:
@@ -394,14 +407,38 @@ def compress_pytree(
     leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
     if sharded is None:
         sharded = any(_is_multidevice(leaf) for _, leaf in leaves)
-    if sharded:
-        return _compress_pytree_sharded(
+    request = next(_requests)
+    with TraceAnnotation(
+        "repro.compress_pytree", request=request, fields=len(leaves),
+        raw_bytes=_leaf_bytes(leaves),
+    ):
+        compress_tree = _compress_pytree_sharded if sharded else _compress_pytree_gathered
+        return compress_tree(
             leaves, treedef, pset, predicate, workers, cache=cache,
-            device_encode=device_encode,
+            device_encode=device_encode, request=request,
         )
-    named, pol_of = _named_leaves_with_policies(
-        leaves, pset, predicate, materialize=True
-    )
+
+
+def _compress_pytree_gathered(
+    leaves: list,
+    treedef: Any,
+    pset: PolicySet,
+    predicate: Callable[[str, Any], bool] | None,
+    workers: int | None,
+    *,
+    cache=None,
+    device_encode: bool = False,
+    request: int,
+) -> CompressedTree:
+    """The default path of `compress_pytree`: every leaf is copied to the
+    host, each policy group is decided in one batch, then the per-field
+    encoders run on the thread pool."""
+    with TraceAnnotation(
+        "repro.compress.materialize", fields=len(leaves), bytes=_leaf_bytes(leaves)
+    ):
+        named, pol_of = _named_leaves_with_policies(
+            leaves, pset, predicate, materialize=True
+        )
     # original arrays go in; the solvers cast to f32 one field at a time
     sel_of: dict[int, Selection] = {}
     for p, idxs in group_by_policy(pol_of).items():
@@ -413,11 +450,16 @@ def compress_pytree(
 
     def encode(i: int) -> CompressedField:
         name, arr = named[i]
-        if i not in sel_of:
-            return CompressedField("raw", arr.tobytes(), arr.shape, str(arr.dtype))
-        # original array in: encode_with_selection casts to f32 internally
-        # but records the true dtype, so decompress restores it
-        return encode_with_selection(arr, sel_of[i], device_encode=device_encode)
+        sel = sel_of.get(i)
+        with TraceAnnotation(
+            "repro.encode", request=request, field=name,
+            codec=sel.codec if sel is not None else "raw", raw_bytes=arr.nbytes,
+        ):
+            if sel is None:
+                return CompressedField("raw", arr.tobytes(), arr.shape, str(arr.dtype))
+            # original array in: encode_with_selection casts to f32 internally
+            # but records the true dtype, so decompress restores it
+            return encode_with_selection(arr, sel, device_encode=device_encode)
 
     n_workers = _default_workers() if workers is None else workers
     if n_workers > 1 and len(named) > 1:
@@ -435,8 +477,10 @@ def _compress_pytree_sharded(
     pset: PolicySet,
     predicate: Callable[[str, Any], bool] | None,
     workers: int | None,
+    *,
     cache=None,
     device_encode: bool = False,
+    request: int,
 ) -> CompressedTree:
     """The shard-local engine behind `compress_pytree(sharded=True)`: one
     `plan_tree` pass per policy group decides every float leaf without
@@ -458,15 +502,20 @@ def _compress_pytree_sharded(
     def encode(i: int):
         name, leaf = named[i]
         plan = plan_of.get(i)
-        if plan is None:
-            arr = np.asarray(leaf)
-            return CompressedField("raw", arr.tobytes(), arr.shape, str(arr.dtype))
-        segments = _sh.encode_plan(leaf, plan, device_encode=device_encode)
-        return ShardedCompressedField(
-            _sh.field_codec(plan.selection.codec, segments),
-            tuple(int(s) for s in np.shape(leaf)),
-            str(leaf.dtype), plan.view_shape, segments, plan.selection,
-        )
+        with TraceAnnotation(
+            "repro.encode", request=request, field=name,
+            codec=plan.selection.codec if plan is not None else "raw",
+            raw_bytes=leaf.nbytes,
+        ):
+            if plan is None:
+                arr = np.asarray(leaf)
+                return CompressedField("raw", arr.tobytes(), arr.shape, str(arr.dtype))
+            segments = _sh.encode_plan(leaf, plan, device_encode=device_encode)
+            return ShardedCompressedField(
+                _sh.field_codec(plan.selection.codec, segments),
+                tuple(int(s) for s in np.shape(leaf)),
+                str(leaf.dtype), plan.view_shape, segments, plan.selection,
+            )
 
     n_workers = _default_workers() if workers is None else workers
     if n_workers > 1 and len(named) > 1:
@@ -487,23 +536,31 @@ def decompress_pytree(ct: CompressedTree) -> Any:
     DESIGN.md §6."""
     from . import sharded as _sh
 
-    def decode(cf) -> np.ndarray:
-        if isinstance(cf, ShardedCompressedField):
-            view = _sh.decode_segments(cf.view_shape, cf.segments)
-            return view.reshape(cf.shape).astype(np.dtype(cf.dtype))
-        # `decompress` handles both raw conventions: selection-less raw
-        # leaves restore exact original-dtype bytes, everything else
-        # decodes through the codec registry (always writeable)
-        return decompress(cf)
+    request = next(_requests)
 
-    fields = list(ct.fields.values())
-    if len(fields) > 1:
-        # the host decoders spend their time in numpy, which releases the GIL
-        with ThreadPoolExecutor(max_workers=_default_workers()) as ex:
-            leaves = list(ex.map(decode, fields))
-    else:
-        leaves = [decode(cf) for cf in fields]
-    return jax.tree_util.tree_unflatten(ct.treedef, leaves)
+    def decode(item) -> np.ndarray:
+        name, cf = item
+        with TraceAnnotation(
+            "repro.decode", request=request, field=name, codec=cf.codec,
+            raw_bytes=int(np.prod(cf.shape)) * _dtype_itemsize(cf.dtype),
+        ):
+            if isinstance(cf, ShardedCompressedField):
+                view = _sh.decode_segments(cf.view_shape, cf.segments)
+                return view.reshape(cf.shape).astype(np.dtype(cf.dtype))
+            # `decompress` handles both raw conventions: selection-less raw
+            # leaves restore exact original-dtype bytes, everything else
+            # decodes through the codec registry (always writeable)
+            return decompress(cf)
+
+    fields = list(ct.fields.items())
+    with TraceAnnotation("repro.decompress_pytree", request=request, fields=len(fields)):
+        if len(fields) > 1:
+            # the host decoders spend their time in numpy, which releases the GIL
+            with ThreadPoolExecutor(max_workers=_default_workers()) as ex:
+                leaves = list(ex.map(decode, fields))
+        else:
+            leaves = [decode(item) for item in fields]
+        return jax.tree_util.tree_unflatten(ct.treedef, leaves)
 
 
 __all__ = [

@@ -50,6 +50,7 @@ from functools import lru_cache as _lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import codecs as _codecs
 from . import estimator as est
@@ -207,12 +208,12 @@ def _sweep_jitted(
         e_sz = est.estimate_sz_many(halo, seg, bounds, delta_f, vr_f, size_f)
         return e_sz.bitrate, e_sz.psnr, e_zfp.bitrate, e_zfp.psnr, ps_meas
 
-    def f(halo, seg, bounds, eb_cf, delta_cf, vr_f, size_f):
+    def solve_sweep(halo, seg, bounds, eb_cf, delta_cf, vr_f, size_f):
         return jax.vmap(eval_one, in_axes=(0, 0, None, None, None, None, None))(
             eb_cf, delta_cf, halo, seg, bounds, vr_f, size_f
         )
 
-    return jax.jit(f)
+    return jax.jit(solve_sweep)
 
 
 @dataclass
@@ -683,6 +684,14 @@ def solve_many(
     elif any(v is not None for v in (target_psnr, target_ratio, eb_abs, eb_rel, r_sp)):
         raise ValueError("pass either policy= or the legacy target kwargs, not both")
     fields = list(fields)
+    with TraceAnnotation("repro.compress.solve", fields=len(fields)):
+        return _solve_many(fields, policy, transform, rounds, cache, names)
+
+
+def _solve_many(
+    fields: list, policy: Policy, transform: str, rounds: int | None, cache, names
+) -> list[TargetSolution]:
+    """`solve_many` after its policy is resolved."""
     mode = policy.mode
     if mode == "raw":
         raise ValueError("solve_many has nothing to solve for Policy.raw()")

@@ -112,7 +112,7 @@ def _moments_jitted(nd: int, n_blocks: int, n_fields: int):
     vr so the f32 prefix sums stay comparable across co-batched fields
     (the `field_sums` contract)."""
 
-    def f(halo, seg, bounds, vr_f):
+    def predictor_moments(halo, seg, bounds, vr_f):
         nohalo = halo[(slice(None),) + (slice(1, None),) * nd]
         d = halo
         for ax in range(1, nd + 1):
@@ -137,7 +137,7 @@ def _moments_jitted(nd: int, n_blocks: int, n_fields: int):
         fmax = jnp.full((n_fields,), -jnp.inf, jnp.float32).at[seg].max(bmax)
         return sums, fmin, fmax
 
-    return jax.jit(f)
+    return jax.jit(predictor_moments)
 
 
 def fingerprint_of(

@@ -28,6 +28,7 @@ from functools import lru_cache as _lru_cache
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import codecs as _codecs
 from . import estimator as est
@@ -143,7 +144,7 @@ def _batched_estimates_jitted(nd: int, n_blocks: int, n_fields: int, transform: 
     not O(fields).
     """
 
-    def f(halo, seg, bounds, eb_f, vr_f, size_f):
+    def select_estimate_batched(halo, seg, bounds, eb_f, vr_f, size_f):
         # the no-halo blocks are the halo blocks minus the leading
         # original-neighbor row on each axis (the boundary mask only ever
         # zeroes those -1 offsets), so one gather serves both estimators
@@ -154,7 +155,7 @@ def _batched_estimates_jitted(nd: int, n_blocks: int, n_fields: int, transform: 
         e_sz = est.estimate_sz_many(halo, seg, bounds, 2.0 * eb_sz, vr_f, size_f)
         return e_sz.bitrate, e_zfp.bitrate, e_zfp.psnr, eb_sz
 
-    return jax.jit(f)
+    return jax.jit(select_estimate_batched)
 
 
 #: per-launch field cap. Two constraints, the second binding: (a) the
@@ -326,33 +327,37 @@ def _build_select_members(
     batch composition then matches the unsharded call exactly, which is
     what makes mixed eligible/fallback pytrees decide bit-identically."""
     groups: dict[int, list[tuple[int, np.ndarray, float, float, int]]] = {}
-    for i, x in zip(indices, fields):
-        arr = np.asarray(x, dtype=np.float32)
-        view = _fold_ndim(arr)
-        vr = float(np.max(view) - np.min(view)) if view.size else 0.0
-        sel0 = _degenerate_selection(view, vr, eb_abs, eb_rel, r_sp)
-        if sel0 is not None:
-            results[i] = sel0
-            continue
-        if eb_abs is None:
-            assert eb_rel is not None, "need eb_abs or eb_rel"
-            eb = eb_rel * vr
-        else:
-            eb = eb_abs
-        starts = est.block_starts(view.shape, r_sp)
-        if len(starts) > _max_batch_blocks(view.ndim):
-            # monster field: bigger alone than a whole batch — the
-            # per-field path has no int32 accumulation to protect
-            results[i] = select(
-                view, eb_abs=float(eb), r_sp=r_sp, transform=transform,
-                codecs=codecs,
-            )
-            continue
-        groups.setdefault(view.ndim, []).append((
-            i,
-            est.gather_blocks_np(view, starts, halo=True),
-            float(eb), vr, view.size,
-        ))
+    span = TraceAnnotation("repro.compress.gather", fields=len(fields))
+    with span:
+        for i, x in zip(indices, fields):
+            arr = np.asarray(x, dtype=np.float32)
+            view = _fold_ndim(arr)
+            vr = float(np.max(view) - np.min(view)) if view.size else 0.0
+            sel0 = _degenerate_selection(view, vr, eb_abs, eb_rel, r_sp)
+            if sel0 is not None:
+                results[i] = sel0
+                continue
+            if eb_abs is None:
+                assert eb_rel is not None, "need eb_abs or eb_rel"
+                eb = eb_rel * vr
+            else:
+                eb = eb_abs
+            starts = est.block_starts(view.shape, r_sp)
+            if len(starts) > _max_batch_blocks(view.ndim):
+                # monster field: bigger alone than a whole batch — the
+                # per-field path has no int32 accumulation to protect
+                with TraceAnnotation("repro.fallback.per_field_select"):
+                    results[i] = select(
+                        view, eb_abs=float(eb), r_sp=r_sp, transform=transform,
+                        codecs=codecs,
+                    )
+                continue
+            groups.setdefault(view.ndim, []).append((
+                i,
+                est.gather_blocks_np(view, starts, halo=True),
+                float(eb), vr, view.size,
+            ))
+        span.set_metadata(blocks=sum(len(m[1]) for ms in groups.values() for m in ms))
     return groups
 
 
@@ -379,7 +384,12 @@ def _run_select_batches(
             ):
                 blocks += len(members[hi][1])
                 hi += 1
-            _select_batch(nd, members[lo:hi], results, r_sp, transform, codecs)
+            # the launch with its input packing: concatenating the samples
+            # into the padded batch costs about as much as the launch itself
+            with TraceAnnotation(
+                "repro.compress.estimate", fields=hi - lo, n_blocks=_next_pow2(blocks)
+            ):
+                _select_batch(nd, members[lo:hi], results, r_sp, transform, codecs)
             lo = hi
 
 
@@ -440,7 +450,7 @@ def _estimates_jitted(x_shape, starts_shape, transform: str):
     the first field (see bench_overhead).
     """
 
-    def f(x, starts, eb_abs, vr):
+    def select_estimate(x, starts, eb_abs, vr):
         e_zfp = est.estimate_zfp(x, eb_abs, starts, vr, transform)
         delta = est.sz_delta_for_psnr(e_zfp.psnr, vr)
         # clamp: degenerate (near-lossless) ZFP PSNR estimates would drive
@@ -449,7 +459,7 @@ def _estimates_jitted(x_shape, starts_shape, transform: str):
         e_sz = est.estimate_sz(x, 2.0 * eb_sz, starts, vr)
         return e_sz.bitrate, e_zfp.bitrate, e_zfp.psnr, eb_sz
 
-    return jax.jit(f)
+    return jax.jit(select_estimate)
 
 
 # ---------------------------------------------------------------------------
@@ -495,15 +505,20 @@ def encode_with_selection(
     if view.ndim == 0:
         view = view.reshape(1)
     codec = _codecs.get(sel.codec)
-    data = None
     if device_encode and getattr(codec, "device_encode", False):
         data = codec.encode_device(view, sel)
-    if data is None:
+        if data is None:
+            with TraceAnnotation("repro.fallback.device_declined"):
+                data = codec.encode(view, sel)
+    else:
         data = codec.encode(view, sel)
     # safety net: never ship a stream larger than raw
     if len(data) >= view.nbytes and sel.codec != "raw":
-        sel = Selection("raw", sel.eb_abs, sel.eb_sz, 32.0, 32.0, sel.psnr_target, sel.vr, sel.r_sp)
-        data = view.tobytes()
+        with TraceAnnotation("repro.fallback.stream_not_smaller"):
+            sel = Selection(
+                "raw", sel.eb_abs, sel.eb_sz, 32.0, 32.0, sel.psnr_target, sel.vr, sel.r_sp
+            )
+            data = view.tobytes()
     return CompressedField(sel.codec, data, orig_shape, str(orig_dtype), sel)
 
 

@@ -65,6 +65,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.runtime import dist
@@ -593,6 +594,15 @@ def plan_tree(
         )
     else:
         raise TypeError(f"expected a Policy (or legacy mode str), got {policy!r}")
+    arrs = list(arrs)
+    with TraceAnnotation("repro.compress.plan", fields=len(arrs)):
+        return _plan_tree(arrs, policy, transform, reconcile, cache, names)
+
+
+def _plan_tree(
+    arrs: list, policy: Policy, transform: str, reconcile: str, cache, names
+) -> list[FieldPlan]:
+    """`plan_tree` after its policy is resolved."""
     mode, r_sp = policy.mode, policy.r_sp
     eb_abs, eb_rel = policy.eb_abs, policy.eb_rel
     codecs = policy.codecs
@@ -615,7 +625,6 @@ def plan_tree(
             )
         target = float(getattr(policy, attr))
 
-    arrs = list(arrs)
     n = len(arrs)
     if cache is not None:
         if names is None:
@@ -908,13 +917,16 @@ def encode_view_segment(
     if sel.codec == "raw":
         return "raw", view32.tobytes()
     codec = _codecs.get(sel.codec)
-    data = None
     if device_encode and getattr(codec, "device_encode", False):
         data = codec.encode_device(view32, sel)
-    if data is None:
+        if data is None:
+            with TraceAnnotation("repro.fallback.device_declined"):
+                data = codec.encode(view32, sel)
+    else:
         data = codec.encode(view32, sel)
     if len(data) >= view32.nbytes:
-        return "raw", view32.tobytes()
+        with TraceAnnotation("repro.fallback.stream_not_smaller"):
+            return "raw", view32.tobytes()
     return sel.codec, data
 
 

@@ -21,6 +21,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import entropy as _entropy
 from .transforms import lorenzo_forward
@@ -131,13 +132,18 @@ def sz_encode_residuals(
     payload, outlier section, container. Split from `sz_compress` so the
     device-encode parity suite can run the host coder on *device-computed*
     residuals and compare streams byte for byte (DESIGN.md §3.7)."""
-    d = np.asarray(d).reshape(-1).astype(np.int64)
-    esc_mask = np.abs(d) > RESIDUAL_RADIUS
-    syms = np.where(esc_mask, 0, d + RESIDUAL_RADIUS + 1).astype(np.int64)
-    freqs = np.bincount(syms, minlength=2 * RESIDUAL_RADIUS + 2)
-    table = _entropy.build_table(freqs)
-    payload = _entropy.encode(syms, table)
-    return sz_container(shape, delta, table, payload, d[esc_mask], magic=magic)
+    d = np.asarray(d).reshape(-1)
+    with TraceAnnotation("repro.sz.table", symbols=d.size):
+        d = d.astype(np.int64)
+        esc_mask = np.abs(d) > RESIDUAL_RADIUS
+        syms = np.where(esc_mask, 0, d + RESIDUAL_RADIUS + 1).astype(np.int64)
+        freqs = np.bincount(syms, minlength=2 * RESIDUAL_RADIUS + 2)
+        table = _entropy.build_table(freqs)
+        outliers = d[esc_mask]
+    with TraceAnnotation("repro.sz.pack"):
+        payload = _entropy.encode(syms, table)
+    with TraceAnnotation("repro.sz.container", outliers=outliers.size):
+        return sz_container(shape, delta, table, payload, outliers, magic=magic)
 
 
 def sz_compress(x: np.ndarray, eb: float) -> bytes:
@@ -145,8 +151,9 @@ def sz_compress(x: np.ndarray, eb: float) -> bytes:
     x = np.asarray(x, dtype=np.float32)
     assert eb > 0, "error bound must be positive"
     delta = 2.0 * float(eb)
-    codes = np.round(np.nan_to_num(x.astype(np.float64) / delta)).astype(np.int64)
-    d = _lorenzo_fwd_np(codes)
+    with TraceAnnotation("repro.sz.quantize"):
+        codes = np.round(np.nan_to_num(x.astype(np.float64) / delta)).astype(np.int64)
+        d = _lorenzo_fwd_np(codes)
     return sz_encode_residuals(d, x.shape, delta)
 
 
@@ -161,18 +168,20 @@ def sz_decompress(buf: bytes) -> np.ndarray:
     off += 8 * ndim
     (tbl_len,) = struct.unpack_from("<I", buf, off)
     off += 4
-    table = _entropy.HuffmanTable.from_bytes(buf[off : off + tbl_len])
-    off += tbl_len
-    (pay_len,) = struct.unpack_from("<Q", buf, off)
-    off += 8
-    syms = _entropy.decode(buf[off : off + pay_len], table, size)
-    off += pay_len
-    outliers = np.frombuffer(buf[off : off + 8 * n_out], dtype=np.int64)
-    d = syms - (RESIDUAL_RADIUS + 1)
-    esc = syms == 0
-    d[esc] = outliers
-    codes = _lorenzo_inv_np(d.reshape(shape))
-    return (codes.astype(np.float64) * delta).astype(np.float32)
+    with TraceAnnotation("repro.sz.unpack"):
+        table = _entropy.HuffmanTable.from_bytes(buf[off : off + tbl_len])
+        off += tbl_len
+        (pay_len,) = struct.unpack_from("<Q", buf, off)
+        off += 8
+        syms = _entropy.decode(buf[off : off + pay_len], table, size)
+        off += pay_len
+    with TraceAnnotation("repro.sz.reconstruct"):
+        outliers = np.frombuffer(buf[off : off + 8 * n_out], dtype=np.int64)
+        d = syms - (RESIDUAL_RADIUS + 1)
+        esc = syms == 0
+        d[esc] = outliers
+        codes = _lorenzo_inv_np(d.reshape(shape))
+        return (codes.astype(np.float64) * delta).astype(np.float32)
 
 
 def sz_compressed_bits(buf: bytes) -> int:

@@ -25,6 +25,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .embedded import (
     align_blocks,
@@ -108,18 +109,20 @@ def _prepare_blocks(x: np.ndarray, eb: float, transform: str):
     n = x.ndim
     T = bot_matrix(transform)  # float64
     gain_n = bot_linf_gain(transform) ** n
-    blocks, padded = blockize(jnp.asarray(x, jnp.float32))
-    blocks = np.asarray(blocks)
+    with TraceAnnotation("repro.zfp.blockize"):
+        blocks, padded = blockize(jnp.asarray(x, jnp.float32))
+        blocks = np.asarray(blocks)
     nblk = blocks.shape[0]
     e = np.empty(nblk, np.int16)
     q = np.empty((nblk, 4**n), np.int64)
-    for lo, hi in _chunks(nblk):
-        b = blocks[lo:hi].astype(np.float64)
-        mx = np.maximum(np.abs(b).reshape(hi - lo, -1).max(axis=1), 1e-30)
-        e[lo:hi] = np.ceil(np.log2(mx))
-        norm = b * np.exp2(-e[lo:hi].astype(np.float64)).reshape((-1,) + (1,) * n)
-        coeffs = _transform_blocks(norm, T).reshape(hi - lo, -1)
-        q[lo:hi] = np.trunc(coeffs / _plane_step(e[lo:hi], eb, gain_n)[:, None])
+    with TraceAnnotation("repro.zfp.quantize", blocks=nblk):
+        for lo, hi in _chunks(nblk):
+            b = blocks[lo:hi].astype(np.float64)
+            mx = np.maximum(np.abs(b).reshape(hi - lo, -1).max(axis=1), 1e-30)
+            e[lo:hi] = np.ceil(np.log2(mx))
+            norm = b * np.exp2(-e[lo:hi].astype(np.float64)).reshape((-1,) + (1,) * n)
+            coeffs = _transform_blocks(norm, T).reshape(hi - lo, -1)
+            q[lo:hi] = np.trunc(coeffs / _plane_step(e[lo:hi], eb, gain_n)[:, None])
     return q, e, _plane_step(e, eb, gain_n), padded, gain_n, T
 
 
@@ -305,7 +308,8 @@ def zfp_encode_quantized(
 def zfp_compress(x: np.ndarray, eb: float, transform: str = "zfp") -> bytes:
     x = np.asarray(x, dtype=np.float32)
     q, e, step, padded, gain_n, _ = _prepare_blocks(x, eb, transform)
-    return zfp_encode_quantized(q, e, x.shape, padded, eb, transform)
+    with TraceAnnotation("repro.zfp.planes"):
+        return zfp_encode_quantized(q, e, x.shape, padded, eb, transform)
 
 
 def zfp_decompress(buf: bytes) -> np.ndarray:
@@ -324,20 +328,23 @@ def zfp_decompress(buf: bytes) -> np.ndarray:
     off += nblk
     (nbits,) = struct.unpack_from("<Q", buf, off)
     off += 8
-    bits = np.unpackbits(np.frombuffer(buf[off:], dtype=np.uint8))[:nbits]
-    bsz = 4**n
-    m, neg, _ = _read_planes(bits, 0, nblk, bsz, nsb.astype(np.int64))
-    del bits
-    inv = np.argsort(_degree_order(n))  # undo the degree-ordered layout
-    step = _plane_step(e, eb, bot_linf_gain(transform) ** n)
-    Tt = bot_matrix(transform).T
-    rec = np.empty((nblk,) + (4,) * n, np.float32)
-    for lo, hi in _chunks(nblk):
-        mm = m[lo:hi][:, inv]
-        mag = np.where(mm > 0, (mm.astype(np.float64) + 0.5) * step[lo:hi, None], 0.0)
-        coeffs = np.where(neg[lo:hi][:, inv], -mag, mag).reshape((-1,) + (4,) * n)
-        rec[lo:hi] = _transform_blocks(coeffs, Tt) * np.exp2(
-            e[lo:hi].astype(np.float64)
-        ).reshape((-1,) + (1,) * n)
-    out = unblockize(jnp.asarray(rec), padded, shape)
-    return np.asarray(out, dtype=np.float32)
+    with TraceAnnotation("repro.zfp.read_planes"):
+        bits = np.unpackbits(np.frombuffer(buf[off:], dtype=np.uint8))[:nbits]
+        bsz = 4**n
+        m, neg, _ = _read_planes(bits, 0, nblk, bsz, nsb.astype(np.int64))
+        del bits
+    with TraceAnnotation("repro.zfp.inverse"):
+        inv = np.argsort(_degree_order(n))  # undo the degree-ordered layout
+        step = _plane_step(e, eb, bot_linf_gain(transform) ** n)
+        Tt = bot_matrix(transform).T
+        rec = np.empty((nblk,) + (4,) * n, np.float32)
+        for lo, hi in _chunks(nblk):
+            mm = m[lo:hi][:, inv]
+            mag = np.where(mm > 0, (mm.astype(np.float64) + 0.5) * step[lo:hi, None], 0.0)
+            coeffs = np.where(neg[lo:hi][:, inv], -mag, mag).reshape((-1,) + (4,) * n)
+            rec[lo:hi] = _transform_blocks(coeffs, Tt) * np.exp2(
+                e[lo:hi].astype(np.float64)
+            ).reshape((-1,) + (1,) * n)
+    with TraceAnnotation("repro.zfp.unblockize"):
+        out = unblockize(jnp.asarray(rec), padded, shape)
+        return np.asarray(out, dtype=np.float32)
