@@ -161,12 +161,14 @@ class CheckpointConfig:
     # to opt into tolerance>0 / warm_start. The cache rides the manifest
     # (`decision_cache` key) so `restore` leaves the next save warm.
     cache: Any = False
-    # device-resident Stage III (DESIGN.md §3.7): when True, codecs that
-    # advertise the `device_encode` capability pack their bitstreams
-    # in-graph and only the packed words cross the interconnect; fields
-    # the device tier declines (fallback rules of §3.7) take the host
-    # coder, so streams stay byte-identical either way
-    device_encode: bool = False
+    # device-resident Stage III (DESIGN.md §3.7): None decides per field
+    # (`selector.encode_tier`: in-graph on a TPU backend for fields of at
+    # least `DEVICE_ENCODE_MIN_VALUES` values), True or False forces a
+    # path. In-graph, codecs that advertise the `device_encode` capability
+    # pack their bitstreams on the device and only the packed words cross
+    # the interconnect; fields the device tier declines (fallback rules of
+    # §3.7) take the host coder, so decoding is the same either way
+    device_encode: bool | None = None
     # multi-host save fencing (DESIGN.md §6.2): how long any host waits at
     # the write/publish barriers before FAILING the save (a straggler or
     # dead host must surface as an exception, never as a hang)

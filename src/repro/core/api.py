@@ -64,6 +64,7 @@ from .selector import (
     Selection,
     compression_ratio,
     decompress,
+    encode_tier,
     encode_with_selection,
     select,
     select_and_compress,
@@ -219,7 +220,7 @@ def compress(
     x: np.ndarray,
     policy: Policy | str | None = None,
     *,
-    device_encode: bool = False,
+    device_encode: bool | None = None,
     mode: str | None = None,
     eb_rel: float | None = None,
     eb_abs: float | None = None,
@@ -248,12 +249,14 @@ def compress(
         `codecs` allowlist restricts
         which registered codecs compete; `r_sp` is the estimator block
         sampling rate (paper default 5%).
-      device_encode: finish Stage III in-graph where the selected codec
-        supports it (capability `device_encode`, DESIGN.md §3.7): packed
-        stream bytes come off the device in one `device_get` instead of
-        raw codes riding a host entropy coder. Decisions are unchanged;
-        fields the device encoders decline (the §3.7 fallback rules)
-        silently take the host coder. Default off.
+      device_encode: where Stage III runs (DESIGN.md §3.7). None (the
+        default) decides per field with `selector.encode_tier`: in-graph
+        on a TPU backend for fields of at least
+        `selector.DEVICE_ENCODE_MIN_VALUES` values, packed stream bytes
+        coming off the device in one `device_get`; on the host coders
+        otherwise. True or False forces one path. Decisions are
+        unchanged; fields the device encoders decline (the §3.7 fallback
+        rules) silently take the host coder.
       mode / eb_rel / eb_abs / target_psnr / target_ratio / r_sp:
         deprecated keyword spelling of the same contract — shimmed onto a
         `Policy` with a `DeprecationWarning`, decisions unchanged.
@@ -325,7 +328,7 @@ def compress_pytree(
     workers: int | None = None,
     sharded: bool | None = None,
     cache=None,
-    device_encode: bool = False,
+    device_encode: bool | None = None,
     eb_rel: float | None = None,
     eb_abs: float | None = None,
     r_sp: float | None = None,
@@ -378,12 +381,14 @@ def compress_pytree(
         drifted or new leaves re-decide and refresh their entry. The
         caller owns the cache object and reuses it across calls
         (`CheckpointManager` persists it in the manifest).
-      device_encode: finish Stage III in-graph for codecs with the
-        `device_encode` capability (DESIGN.md §3.7) — the thread-pool
-        encoders fetch packed stream bytes instead of running the host
-        entropy coder. Applies on both the gathered and the shard-local
-        (`sharded=True`) paths; decisions and manifests are unchanged,
-        and declined fields fall back to the host coder per field.
+      device_encode: where Stage III runs, as for `compress`: None
+        decides per field from the backend and the field's size
+        (`selector.encode_tier`), True or False forces one path. On the
+        device tier the thread-pool encoders fetch packed stream bytes
+        instead of running the host entropy coder. Applies on both the
+        gathered and the shard-local (`sharded=True`) paths; decisions
+        and manifests are unchanged, and declined fields fall back to the
+        host coder per field.
       eb_rel / eb_abs / r_sp / mode / target_psnr / target_ratio /
         predicate: the deprecated kwarg spelling — shimmed onto a `Policy`
         (predicate rejections onto per-leaf raw) with a
@@ -427,7 +432,7 @@ def _compress_pytree_gathered(
     workers: int | None,
     *,
     cache=None,
-    device_encode: bool = False,
+    device_encode: bool | None = None,
     request: int,
 ) -> CompressedTree:
     """The default path of `compress_pytree`: every leaf is copied to the
@@ -451,15 +456,17 @@ def _compress_pytree_gathered(
     def encode(i: int) -> CompressedField:
         name, arr = named[i]
         sel = sel_of.get(i)
+        codec = sel.codec if sel is not None else "raw"
+        tier = encode_tier(codec, arr.size, device_encode)
         with TraceAnnotation(
-            "repro.encode", request=request, field=name,
-            codec=sel.codec if sel is not None else "raw", raw_bytes=arr.nbytes,
+            "repro.encode", request=request, field=name, codec=codec,
+            raw_bytes=arr.nbytes, tier=tier,
         ):
             if sel is None:
                 return CompressedField("raw", arr.tobytes(), arr.shape, str(arr.dtype))
             # original array in: encode_with_selection casts to f32 internally
             # but records the true dtype, so decompress restores it
-            return encode_with_selection(arr, sel, device_encode=device_encode)
+            return encode_with_selection(arr, sel, device_encode=tier == "device")
 
     n_workers = _default_workers() if workers is None else workers
     if n_workers > 1 and len(named) > 1:
@@ -479,7 +486,7 @@ def _compress_pytree_sharded(
     workers: int | None,
     *,
     cache=None,
-    device_encode: bool = False,
+    device_encode: bool | None = None,
     request: int,
 ) -> CompressedTree:
     """The shard-local engine behind `compress_pytree(sharded=True)`: one
@@ -502,15 +509,16 @@ def _compress_pytree_sharded(
     def encode(i: int):
         name, leaf = named[i]
         plan = plan_of.get(i)
+        codec = plan.selection.codec if plan is not None else "raw"
+        tier = encode_tier(codec, leaf.size, device_encode)
         with TraceAnnotation(
-            "repro.encode", request=request, field=name,
-            codec=plan.selection.codec if plan is not None else "raw",
-            raw_bytes=leaf.nbytes,
+            "repro.encode", request=request, field=name, codec=codec,
+            raw_bytes=leaf.nbytes, tier=tier,
         ):
             if plan is None:
                 arr = np.asarray(leaf)
                 return CompressedField("raw", arr.tobytes(), arr.shape, str(arr.dtype))
-            segments = _sh.encode_plan(leaf, plan, device_encode=device_encode)
+            segments = _sh.encode_plan(leaf, plan, device_encode=tier == "device")
             return ShardedCompressedField(
                 _sh.field_codec(plan.selection.codec, segments),
                 tuple(int(s) for s in np.shape(leaf)),

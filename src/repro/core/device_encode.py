@@ -13,7 +13,7 @@ so the only transfer per field is one `jax.device_get` of packed words
   fetched histogram (tiny — `entropy.build_table` on O(2^16) symbols) and
   knows the exact payload size (`sum(freqs * lens)`); pass 2 jits the
   table-lookup code/length gather, the exclusive prefix-sum of lengths,
-  and the word-major `pack_codes_gather`. Escape literals ride the same
+  and the scatter `pack_codes`. Escape literals ride the same
   launch: a rank-indexed `searchsorted` gather compacts outlier residuals
   into the container's int64 section. The stream is the SZJ1 layout under
   the versioned `SZJ2` magic (`sz.DEVICE_MAGIC`) — `sz_decompress`
@@ -58,6 +58,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import ops, pack
 
@@ -102,19 +103,18 @@ def _sz_pass1(x, eb):
     return d, syms, hist, amax
 
 
-@functools.partial(jax.jit, static_argnames=("n_words", "esc_cap", "window"))
-def _sz_pass2(syms, d, lut_codes, lut_lens, *, n_words, esc_cap, window):
+@functools.partial(jax.jit, static_argnames=("n_words", "esc_cap"))
+def _sz_pass2(syms, d, lut_codes, lut_lens, *, n_words, esc_cap):
     """Table-lookup gather + prefix-sum pack, and escape compaction.
 
-    The packer is the gather form (`pack_codes_gather`): every emitted
-    symbol has a code (`len >= 1`), so each arena word overlaps a bounded
-    window of codes. Escapes compact by rank through `searchsorted` on the
+    The packer is the scatter form (`pack_codes`), whose writes arrive in
+    offset order. Escapes compact by rank through `searchsorted` on the
     escape-count prefix sum — `esc_cap` gathers instead of a full-length
     scatter."""
     lens = lut_lens[syms]
     codes = lut_codes[syms]
     offsets = jnp.cumsum(lens) - lens  # exclusive
-    words = pack.pack_codes_gather(codes, lens, offsets, n_words, window)
+    words = pack.pack_codes(codes, lens, offsets, n_words)
     esc_rank = jnp.cumsum((syms == 0).astype(jnp.int32))
     tgt = jnp.arange(1, max(esc_cap, 1) + 1, dtype=jnp.int32)
     idx = jnp.clip(
@@ -143,14 +143,15 @@ def sz_encode_device(x, eb: float) -> bytes | None:
     delta32 = np.float32(2.0) * np.float32(eb)
     if not np.isfinite(float(delta32)) or float(delta32) <= 0.0:
         return None
-    x32 = jnp.asarray(x, jnp.float32)
-    d, syms, hist, amax = _sz_pass1(x32, jnp.float32(eb))
-    freqs, amax = jax.device_get((hist, amax))
+    with TraceAnnotation("repro.device.sz.pass1"):
+        d, syms, hist, amax = _sz_pass1(jnp.asarray(x, jnp.float32), jnp.float32(eb))
+        freqs, amax = jax.device_get((hist, amax))
     amax = float(amax)
     if not np.isfinite(amax) or amax / float(delta32) >= _SZ_CODE_LIMIT:
         return None
     freqs = np.asarray(freqs, dtype=np.int64)
-    table = _entropy.build_table(freqs)
+    with TraceAnnotation("repro.device.sz.table"):
+        table = _entropy.build_table(freqs)
     payload_bits = int((freqs * table.lens.astype(np.int64)).sum())
     if payload_bits > _MAX_STREAM_BITS:
         return None
@@ -162,16 +163,14 @@ def sz_encode_device(x, eb: float) -> bytes | None:
     # short buffer silently truncate, so guard the invariant anyway
     if 32 * n_words < payload_bits or esc_cap < n_esc:
         return None
-    emitted = table.lens[(freqs > 0) & (table.lens > 0)]
-    min_len = int(emitted.min()) if emitted.size else 1
-    words, escapes = _sz_pass2(
-        syms, d,
-        jnp.asarray(table.codes.astype(np.uint32)),
-        jnp.asarray(table.lens.astype(np.int32)),
-        n_words=n_words, esc_cap=esc_cap,
-        window=pack.gather_window(min_len),
-    )
-    words_np, esc_np = jax.device_get((words, escapes))
+    with TraceAnnotation("repro.device.sz.pass2", words=n_words):
+        words, escapes = _sz_pass2(
+            syms, d,
+            jnp.asarray(table.codes.astype(np.uint32)),
+            jnp.asarray(table.lens.astype(np.int32)),
+            n_words=n_words, esc_cap=esc_cap,
+        )
+        words_np, esc_np = jax.device_get((words, escapes))
     payload = pack.words_to_bytes(words_np, payload_bits)
     outliers = np.asarray(esc_np[:n_esc], dtype=np.int64)
     # container delta is the float32 value the device divided by, so the
@@ -188,13 +187,25 @@ def sz_encode_device(x, eb: float) -> bytes | None:
 
 @functools.partial(jax.jit, static_argnames=("transform",))
 def _zfp_pass1(x, *, transform):
-    """Blockize + exponent-align + BOT (all §3 jit-safe pieces, f32)."""
+    """Blockize + exponent-align + BOT (all §3 jit-safe pieces, f32).
+
+    The transform's contractions run at float32 precision: the TPU's
+    default would round their inputs to bfloat16, an error the decoder's
+    float64 inverse passes through to the reconstruction, past the bound."""
     n = x.ndim
     T = jnp.asarray(bot_matrix(transform), jnp.float32)
     blocks, _ = blockize(x.astype(jnp.float32))
     norm, e = align_blocks(blocks)
-    coeffs = block_transform_nd(norm, T, n)
+    with jax.default_matmul_precision("highest"):
+        coeffs = block_transform_nd(norm, T, n)
     return coeffs, e
+
+
+def _bit_length(m):
+    """Bits of each non-negative int32 (0 for 0), exact: a float `log2`
+    is an approximation on some backends, and one short at a power of two
+    would move a coefficient to the wrong plane."""
+    return 32 - jax.lax.clz(m)
 
 
 @functools.partial(jax.jit, static_argnames=("nd",))
@@ -210,16 +221,8 @@ def _zfp_pass2a(coeffs, step, *, nd):
     m = jnp.minimum(mf, 2.0**31 - 1).astype(jnp.int32)
     neg = c < 0
     mx = jnp.max(m, axis=1) if m.size else jnp.zeros((nblk,), jnp.int32)
-    nsb = jnp.where(
-        mx > 0,
-        jnp.floor(jnp.log2(jnp.maximum(mx.astype(jnp.float32), 1.0))) + 1.0,
-        0.0,
-    ).astype(jnp.int32)
-    nsb_c = jnp.where(
-        m > 0,
-        jnp.floor(jnp.log2(jnp.maximum(m.astype(jnp.float32), 1.0))) + 1.0,
-        0.0,
-    )
+    nsb = _bit_length(mx)
+    nsb_c = _bit_length(m).astype(jnp.float32)
     # the block_bits payload model: w*maxplane + sum(nsb) + 2*nsig per block
     # (headers live in the e/nsb sidecars, not the packed payload)
     model = (
@@ -256,8 +259,7 @@ def _zfp_pass2b(m, neg, nsb, *, n_words, n_planes):
     nblk, bsz = m.shape
     w = int(np.ceil(np.log2(bsz + 1)))
     P = n_planes
-    mf = jnp.maximum(m, 1).astype(jnp.float32)
-    nc = jnp.where(m > 0, jnp.floor(jnp.log2(mf)) + 1.0, 0.0).astype(jnp.int8)
+    nc = _bit_length(m).astype(jnp.int8)
     t_ax = jnp.arange(1, P + 2, dtype=jnp.int8)[:, None, None]
     ge = nc[None] >= t_ax  # (P+1, nblk, bsz)
     g8 = ge.astype(jnp.int8)
@@ -354,21 +356,22 @@ def zfp_encode_device(x, eb: float, transform: str = "zfp") -> bytes | None:
     size = int(np.prod(shape, dtype=np.int64)) if shape else 0
     if size == 0 or eb <= 0 or not np.isfinite(eb):
         return None
-    x32 = jnp.asarray(x, jnp.float32)
-    nd = x32.ndim
+    nd = len(shape)
     bsz = 4**nd
     w = int(np.ceil(np.log2(bsz + 1)))
     padded = tuple(s + (-s) % 4 for s in shape)
-    coeffs, e = _zfp_pass1(x32, transform=transform)
-    e_np = np.asarray(jax.device_get(e), dtype=np.int16)
+    with TraceAnnotation("repro.device.zfp.pass1"):
+        coeffs, e = _zfp_pass1(jnp.asarray(x, jnp.float32), transform=transform)
+        e_np = np.asarray(jax.device_get(e), dtype=np.int16)
     nblk = len(e_np)
     step = _zfp_step(e_np, eb, bot_linf_gain(transform) ** nd)
     if step is None:
         return None
-    m, neg, nsb, model, maxp, mmax = _zfp_pass2a(
-        coeffs, jnp.asarray(step), nd=nd
-    )
-    model, maxp, mmax = jax.device_get((model, maxp, mmax))
+    with TraceAnnotation("repro.device.zfp.pass2a"):
+        m, neg, nsb, model, maxp, mmax = _zfp_pass2a(
+            coeffs, jnp.asarray(step), nd=nd
+        )
+        model, maxp, mmax = jax.device_get((model, maxp, mmax))
     if not np.isfinite(float(mmax)) or float(mmax) >= _ZFP_MAG_LIMIT:
         return None
     n_planes = min(24, -(-int(maxp) // 4) * 4) if int(maxp) else 0
@@ -376,8 +379,9 @@ def zfp_encode_device(x, eb: float, transform: str = "zfp") -> bytes | None:
     if nblk * (3 * bsz + w) * max(n_planes, 1) > _MAX_STREAM_BITS:
         return None
     n_words = pack.arena_words(float(model))
-    words, total = _zfp_pass2b(m, neg, nsb, n_words=n_words, n_planes=n_planes)
-    words_np, total_bits, nsb_np = jax.device_get((words, total, nsb))
+    with TraceAnnotation("repro.device.zfp.pass2b", words=n_words, planes=n_planes):
+        words, total = _zfp_pass2b(m, neg, nsb, n_words=n_words, n_planes=n_planes)
+        words_np, total_bits, nsb_np = jax.device_get((words, total, nsb))
     total_bits = int(total_bits)
     if total_bits > 32 * n_words:
         # the block_bits model under-estimated past the pow2 slack: the

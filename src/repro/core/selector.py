@@ -480,8 +480,50 @@ class CompressedField:
         return len(self.data)
 
 
+#: Stage III runs on the chip only for fields of at least this many values:
+#: the smallest size at which the device tier beat the host coders for both
+#: codecs, warm, in `tools/encode_tiers.py` on one v5e (PERF.md §7). At 2^18
+#: values the host SZ coder still won (0.18 s against 0.19 s); a device
+#: encode pays a launch and a fetch per pass, and a compile per pow2 arena
+#: bucket.
+DEVICE_ENCODE_MIN_VALUES = 1 << 20
+
+
+def encode_tier(
+    codec: Codec, n_values: int, device_encode: bool | None = None
+) -> str:
+    """Where one field's Stage III runs: "device" (the codec's in-graph
+    encoder, capability `device_encode`, DESIGN.md §3.7) or "host".
+
+    `device_encode=None` decides from what the caller can observe: the
+    device tier runs when the arrays live on a TPU backend and the field
+    holds at least `DEVICE_ENCODE_MIN_VALUES` values. True or False forces
+    a path; codecs without the capability always encode on the host."""
+    if not getattr(_codecs.get(codec), "device_encode", False):
+        return "host"
+    if device_encode is None:
+        device_encode = (
+            n_values >= DEVICE_ENCODE_MIN_VALUES and jax.default_backend() == "tpu"
+        )
+    return "device" if device_encode else "host"
+
+
+def encode_view(view32: np.ndarray, sel: Selection, tier: str) -> bytes:
+    """The codec's stream for one folded f32 view on `tier` (`encode_tier`).
+    A device encoder that declines (None, the §3.7 fallback rules) hands
+    the field to the host coder: never a truncated stream."""
+    codec = _codecs.get(sel.codec)
+    if tier == "device":
+        data = codec.encode_device(view32, sel)
+        if data is not None:
+            return data
+        with TraceAnnotation("repro.fallback.device_declined"):
+            return codec.encode(view32, sel)
+    return codec.encode(view32, sel)
+
+
 def encode_with_selection(
-    x: np.ndarray, sel: Selection, *, device_encode: bool = False
+    x: np.ndarray, sel: Selection, *, device_encode: bool | None = None
 ) -> CompressedField:
     """Step 4: run the already-selected compressor on `x`.
 
@@ -492,26 +534,19 @@ def encode_with_selection(
     registry (DESIGN.md §2.1), so registered codecs beyond sz/zfp encode
     through the same path.
 
-    `device_encode=True` tries the codec's in-graph Stage III first
-    (capability `device_encode`, DESIGN.md §3.7): the packed stream comes
-    back in one `device_get` and decodes through the same registry
-    decoder. Encoders return None under the §3.7 fallback rules, and the
-    host coder then runs — same container either way, never a truncated
-    stream.
+    `encode_tier` decides where Stage III runs (`device_encode=None`:
+    from the backend and the field's size; True/False force a path). On
+    the device tier the packed stream comes back in one `device_get` and
+    decodes through the same registry decoder. Encoders return None under
+    the §3.7 fallback rules, and the host coder then runs — same container
+    either way, never a truncated stream.
     """
     x = np.asarray(x)
     orig_shape, orig_dtype = x.shape, x.dtype
     view = _fold_ndim(x.astype(np.float32))
     if view.ndim == 0:
         view = view.reshape(1)
-    codec = _codecs.get(sel.codec)
-    if device_encode and getattr(codec, "device_encode", False):
-        data = codec.encode_device(view, sel)
-        if data is None:
-            with TraceAnnotation("repro.fallback.device_declined"):
-                data = codec.encode(view, sel)
-    else:
-        data = codec.encode(view, sel)
+    data = encode_view(view, sel, encode_tier(sel.codec, view.size, device_encode))
     # safety net: never ship a stream larger than raw
     if len(data) >= view.nbytes and sel.codec != "raw":
         with TraceAnnotation("repro.fallback.stream_not_smaller"):

@@ -905,25 +905,19 @@ def _local_device(devices: tuple) -> Any:
 
 
 def encode_view_segment(
-    view32: np.ndarray, sel: Selection, *, device_encode: bool = False
+    view32: np.ndarray, sel: Selection, *, device_encode: bool | None = None
 ) -> tuple[str, bytes]:
     """Step 4 on one (shard of a) folded f32 view, mirroring
     `selector.encode_with_selection` including the never-bigger-than-raw
     safety net — applied per shard, so an incompressible shard of a
     compressible field degrades alone (DESIGN.md §6). Dispatches through
-    the codec registry (DESIGN.md §2.1); with `device_encode`, codecs
-    advertising the capability finish Stage III in-graph first and the
-    host coder only runs when the device tier declines (DESIGN.md §3.7)."""
+    the codec registry (DESIGN.md §2.1); `selector.encode_tier` decides
+    whether Stage III runs in-graph (DESIGN.md §3.7), and the host coder
+    runs when it does not or when the device tier declines."""
     if sel.codec == "raw":
         return "raw", view32.tobytes()
-    codec = _codecs.get(sel.codec)
-    if device_encode and getattr(codec, "device_encode", False):
-        data = codec.encode_device(view32, sel)
-        if data is None:
-            with TraceAnnotation("repro.fallback.device_declined"):
-                data = codec.encode(view32, sel)
-    else:
-        data = codec.encode(view32, sel)
+    tier = select_mod.encode_tier(sel.codec, view32.size, device_encode)
+    data = select_mod.encode_view(view32, sel, tier)
     if len(data) >= view32.nbytes:
         with TraceAnnotation("repro.fallback.stream_not_smaller"):
             return "raw", view32.tobytes()
@@ -935,7 +929,7 @@ def encode_plan(
     plan: FieldPlan,
     host: int | None = None,
     *,
-    device_encode: bool = False,
+    device_encode: bool | None = None,
 ) -> list[Segment]:
     """Encode one field's bytes under its plan: per unique shard when the
     layout allows (each host touches only bytes it already holds), one
@@ -949,8 +943,12 @@ def encode_plan(
     shard is written exactly once, by the process holding its lowest-id
     replica (`dist.owner_host`, the same rule on every host, so the
     per-host partition needs no coordination); gather-fallback fields
-    write their single segment on host 0 (DESIGN.md §6.2)."""
+    write their single segment on host 0 (DESIGN.md §6.2). Where Stage
+    III runs is decided once for the whole field (`selector.encode_tier`
+    on its size), so every segment of a field takes the same tier."""
     sel = plan.selection
+    tier = select_mod.encode_tier(sel.codec, math.prod(plan.view_shape), device_encode)
+    device_encode = tier == "device"
     if not plan.sharded:
         if host is not None and host != 0:
             return []

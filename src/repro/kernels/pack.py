@@ -5,18 +5,13 @@ path; this module is the jit-safe core that moves it in-graph. Both device
 encoders (`core/device_encode.py`) reduce their variable-length emissions
 to the same primitive: a *monotone* sequence of (bit-offset, value, length)
 writes into a preallocated uint32 word arena — no data-dependent control
-flow, no data-dependent shapes. Two realizations of that primitive live
-here, chosen by what the caller can promise:
-
-* `pack_codes` — scatter form: each write lands in at most two words via
-  masked shift/or scatter-adds. Tolerates zero-length writes, so it merges
-  the ZFP chunk emitter's mostly-empty slot grid.
-* `pack_codes_gather` — gather form: each *word* sums the shifted
-  contributions of the bounded window of codes that can overlap it
-  (`searchsorted` on the offset prefix sum finds the first). Requires
-  every length >= 1 — the SZ Huffman stream qualifies (every emitted
-  symbol has a code) — and on the 2-core XLA:CPU backend it beats the
-  scatter form by avoiding the serialized scatter loop entirely.
+flow, no data-dependent shapes. `pack_codes` realizes it as two
+scatter-adds (each write lands in at most two words) and tolerates
+zero-length writes, so it merges the ZFP chunk emitter's mostly-empty
+slot grid as well as the SZ Huffman stream. On a v5e the scatter form
+packs a 100x500x500 SZ field's 2^24-word arena in 0.44 s, where a
+word-major gather form (each word searching for the window of codes that
+overlap it) took 19 s (PERF.md §6).
 
 Layout contract (what makes the arena byte-compatible with the host
 coders): bit `b` of the stream lives in word `b >> 5` at bit `31 - (b & 31)`
@@ -34,10 +29,10 @@ writes (the rate model under-estimated) fall in `mode='drop'`: the arena
 can *truncate* but never corrupt, and the caller detects truncation from
 the true total bit count (DESIGN.md §3.7 fallback rules).
 
-On TPU these lower to XLA scatters/gathers over VMEM-resident arenas; on
-CPU the same program runs through the XLA:CPU path (the kernels' interpret
-tier, DESIGN.md §3.3), which is what the `device_encode_speedup` bench
-gate ratio measures.
+On TPU this lowers to XLA scatters with sorted indices; on CPU the same
+program runs through the XLA:CPU path (the kernels' interpret tier,
+DESIGN.md §3.3), which is what the `device_encode_speedup` bench gate
+ratio measures.
 """
 
 from __future__ import annotations
@@ -99,64 +94,6 @@ def pack_codes(
     return words
 
 
-def gather_window(min_len: int) -> int:
-    """Static gather window for `pack_codes_gather`: an upper bound on how
-    many codes can overlap one 32-bit word when every code is at least
-    `min_len` bits — one straddling the word start plus `32 // min_len`
-    starting inside it, +1 slack. Bucketed to a small set so streams with
-    different tables share compiled packers (the §1 bucketing rule)."""
-    need = WORD_BITS // max(int(min_len), 1) + 2
-    for cap in (6, 10, 18, 34):
-        if need <= cap:
-            return cap
-    return 34
-
-
-def pack_codes_gather(
-    codes: jnp.ndarray,
-    lens: jnp.ndarray,
-    offsets: jnp.ndarray,
-    n_words: int,
-    window: int,
-) -> jnp.ndarray:
-    """Pack variable-length codes (MSB-first) into a fresh word arena
-    (gather form): word `i` is the OR (sum — bits never collide) of the
-    shifted contributions of the codes overlapping bits [32i, 32i+32).
-
-    Contract: every `lens[i]` is in [1, 32] (no dead slots — the window
-    bound breaks otherwise) and `window >= 32 // min(lens) + 2`
-    (`gather_window`). `offsets` is the exclusive prefix sum of `lens`.
-    Words past the last code read dead lanes and come out zero, so the
-    pow2 arena slack is harmless.
-
-    The window is walked one slot at a time over (n_words,) vectors: a
-    (n_words, window) temporary would pad its short minor dim to a full
-    128-lane tile on TPU (21x the bytes at window 6 — 32 GiB for a 512^3
-    field's arena).
-    """
-    n = codes.shape[0]
-    starts = jnp.arange(n_words, dtype=jnp.int32) * WORD_BITS
-    first = jnp.searchsorted(offsets, starts, side="right").astype(jnp.int32) - 1
-    first = jnp.clip(first, 0, max(n - 1, 0))
-    words = jnp.zeros((n_words,), jnp.uint32)
-    for k in range(window):
-        j = first + k
-        jc = jnp.minimum(j, max(n - 1, 0))
-        off = offsets[jc]
-        ln = lens[jc].astype(jnp.int32)
-        c = codes[jc].astype(jnp.uint32)
-        # t: how many bits of code j extend past this word's start
-        t = off + ln - starts
-        live = (j < n) & (t > 0) & (off < starts + WORD_BITS)
-        contrib = jnp.where(
-            t > WORD_BITS,
-            c >> jnp.clip(t - WORD_BITS, 0, WORD_BITS - 1).astype(jnp.uint32),
-            c << jnp.clip(WORD_BITS - t, 0, WORD_BITS - 1).astype(jnp.uint32),
-        )
-        words = words + jnp.where(live, contrib, jnp.uint32(0))
-    return words
-
-
 def words_to_bytes(words: np.ndarray, nbits: int) -> bytes:
     """Host finalizer: big-endian word arena -> the exact `np.packbits`
     byte stream for `nbits` bits. Bits past `nbits` were never written
@@ -169,8 +106,6 @@ def words_to_bytes(words: np.ndarray, nbits: int) -> bytes:
 __all__ = [
     "WORD_BITS",
     "arena_words",
-    "gather_window",
     "pack_codes",
-    "pack_codes_gather",
     "words_to_bytes",
 ]

@@ -9,6 +9,7 @@ host encoder the device's codes and compare bytes, independent of the
 f32-vs-f64 quantization boundary noted in the module docstring.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -222,3 +223,81 @@ def test_kv_page_device_encode_roundtrip():
     raw = kvcomp.compress_page(page, Policy.raw(), device_encode=True)
     assert raw.codec == "raw"
     assert np.array_equal(kvcomp.decompress_page(raw), page)
+
+
+# ---------------------------------------------------------------------------
+# where Stage III runs: `selector.encode_tier`
+# ---------------------------------------------------------------------------
+
+
+class _StubCodec:
+    """Records which of its encoders ran; `declines` makes the device
+    encoder hand the field back (None), as the §3.7 fallback rules do."""
+
+    name, blockwise, pointwise_bound, lossless = "stub", False, True, False
+    device_encode = True
+
+    def __init__(self, declines: bool):
+        self.declines = declines
+        self.calls: list[str] = []
+
+    def encode(self, view, sel):
+        self.calls.append("host")
+        return b"host"
+
+    def encode_device(self, view, sel):
+        self.calls.append("device")
+        return None if self.declines else b"device"
+
+    def decode(self, data):
+        raise NotImplementedError
+
+
+N_MIN = selector.DEVICE_ENCODE_MIN_VALUES
+
+
+@pytest.mark.parametrize("backend,flag,n,declines,want", [
+    ("cpu", None, N_MIN, False, ["host"]),
+    ("cpu", True, 4096, False, ["device"]),
+    ("tpu", None, N_MIN, False, ["device"]),
+    ("tpu", None, N_MIN - 1, False, ["host"]),
+    ("tpu", False, N_MIN, False, ["host"]),
+    ("tpu", None, N_MIN, True, ["device", "host"]),
+])
+def test_encode_tier_decides_from_backend_and_size(monkeypatch, backend, flag, n, declines,
+                                                   want):
+    """`encode_with_selection` and `encode_view_segment` share one decision:
+    the device tier on a TPU backend at or above the size threshold, the
+    host coder below it, on CPU, or where the flag forces it; a decline
+    runs the host coder under `repro.fallback.device_declined`."""
+    from repro.core import sharded
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    stub = _StubCodec(declines)
+    monkeypatch.setitem(codecs._REGISTRY, "stub", stub)
+    spans = []
+
+    class Recorder(selector.TraceAnnotation):
+        def __init__(self, name, **kwargs):
+            spans.append(name)
+            super().__init__(name, **kwargs)
+
+    monkeypatch.setattr(selector, "TraceAnnotation", Recorder)
+    sel = selector.Selection("stub", 1e-3, 1e-3, 8.0, 9.0, 60.0, 1.0, 0.05)
+    x = np.zeros((n,), np.float32)
+    assert selector.encode_tier("stub", n, flag) == want[0]
+    cf = selector.encode_with_selection(x, sel, device_encode=flag)
+    assert (cf.codec, cf.data) == ("stub", want[-1].encode())
+    assert sharded.encode_view_segment(x, sel, device_encode=flag) == ("stub", cf.data)
+    assert stub.calls == want * 2
+    assert spans == ["repro.fallback.device_declined"] * 2 * declines
+    if backend == "cpu" and flag is None:
+        # the real codecs: the default streams are the host coders' bytes
+        rng = np.random.default_rng(9)
+        tree = {"walk": np.cumsum(rng.standard_normal((64, 64)), 0).astype(np.float32),
+                "waves": np.sin(np.linspace(0, 300, 4096)).astype(np.float32).reshape(64, 64)}
+        pol = Policy.fixed_accuracy(eb_rel=1e-4)
+        ct = api.compress_pytree(tree, pol)
+        host = api.compress_pytree(tree, pol, device_encode=False)
+        assert {k: (f.codec, f.data) for k, f in ct.fields.items()} == {
+            k: (f.codec, f.data) for k, f in host.fields.items()}

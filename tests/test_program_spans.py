@@ -80,8 +80,11 @@ def test_field_spans_carry_their_args(traced):
         args = {a["field"]: a for _, _, a in spans}
         assert set(args) == set(tree)
         for name, a in args.items():
-            assert a == {"request": req, "field": name, "codec": ct.fields[name].codec,
-                         "raw_bytes": tree[name].nbytes}
+            want = {"request": req, "field": name, "codec": ct.fields[name].codec,
+                    "raw_bytes": tree[name].nbytes}
+            if half == "encode":
+                want["tier"] = "host"  # the CPU backend keeps Stage III on the host
+            assert a == want
 
 
 def test_selection_spans_inside_the_request(traced):
@@ -118,6 +121,28 @@ def test_program_spans_stay_out_of_the_harness_spans(traced):
         assert not tracing._is_span(name)
     # one SZ, one ZFP and one raw field: 23 spans (3 SZ + 1 ZFP open 37)
     assert len(trace["program_spans"]) == 23
+
+
+def test_device_tier_spans_nest_in_their_field(tmp_path):
+    """Forced onto the device tier, every lossy field's `repro.encode`
+    reads `tier="device"` and holds its codec's `repro.device.*` pass
+    spans on the same thread; the raw field stays on the host."""
+    tree = _tree()
+    _, trace = _profiled(
+        lambda: api.compress_pytree(tree, POLICY, device_encode=True), tmp_path)
+    rows = trace["program_spans"]
+    fields = [r for r in rows if r[0] == "repro.encode"]
+    assert {r[4]["field"]: r[4]["tier"] for r in fields} == {
+        "smooth": "device", "waves": "device", "flat": "host"}
+    device = [r for r in rows if r[0].startswith("repro.device.")]
+    assert {r[0] for r in device} == {
+        "repro.device.sz.pass1", "repro.device.sz.table", "repro.device.sz.pass2",
+        "repro.device.zfp.pass1", "repro.device.zfp.pass2a", "repro.device.zfp.pass2b"}
+    for name, s, d, thread, _ in device:
+        codec = name.split(".")[2]
+        assert any(t == thread and a["codec"] == codec and fs <= s and s + d <= fs + fd
+                   for _, fs, fd, t, a in fields), name
+    assert not [r for r in rows if r[0].startswith("repro.fallback.")]
 
 
 def test_restored_fields_within_bound(traced):

@@ -73,21 +73,49 @@ def test_bot_fused_compiles(one_chip, shape):
     _compile(fn, shape, bot4.tile(shape), one_chip)
 
 
-def test_device_sz_pack_fits_one_chip(one_chip):
-    """The device SZ encoder's packing pass (`device_encode=True`) at NYX
-    512^3 fits the chip's 16 GiB (a (words, window) temporary once padded
-    to 32 GiB). Its Lorenzo pass is the kernel compiled above."""
+def _fits_one_chip(compiled) -> None:
+    """Temp, argument and output bytes under 12 of the chip's 16 GiB."""
+    mem = compiled.memory_analysis()
+    total = mem.temp_size_in_bytes + mem.argument_size_in_bytes + mem.output_size_in_bytes
+    assert total < 12 * 2**30, total
+
+
+def _lower_sz_pass2(sharding, shape, n_words):
     from repro.core import device_encode as de
 
     def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     lut = (de.N_SYMBOLS,)
-    syms = (NYX[0] * NYX[1] * NYX[2],)  # pass 1 hands pass 2 the flat stream
-    p2 = de._sz_pass2.lower(
-        sds(syms, jnp.int32), sds(NYX, jnp.int32), sds(lut, jnp.uint32), sds(lut, jnp.int32),
-        n_words=1 << 26, esc_cap=1 << 10, window=6,
+    syms = (shape[0] * shape[1] * shape[2],)  # pass 1 hands pass 2 the flat stream
+    return de._sz_pass2.lower(
+        sds(syms, jnp.int32), sds(shape, jnp.int32), sds(lut, jnp.uint32),
+        sds(lut, jnp.int32), n_words=n_words, esc_cap=1 << 10,
+    )
+
+
+def test_device_sz_pack_fits_one_chip(one_chip):
+    """The device SZ encoder's packing pass at NYX 512^3 fits the chip's
+    16 GiB. Its Lorenzo pass is the kernel compiled above."""
+    _fits_one_chip(_lower_sz_pass2(one_chip, NYX, 1 << 26).compile())
+
+
+def test_device_sz_pack_fits_one_chip_hurricane(one_chip):
+    """The same pass at the Hurricane-ISABEL field that the benchmark's
+    cell encodes on the chip by default."""
+    _fits_one_chip(_lower_sz_pass2(one_chip, HURRICANE, 1 << 24).compile())
+
+
+def test_device_zfp_emitter_fits_one_chip(one_chip):
+    """The device ZFP plane emitter for every block of a Hurricane-ISABEL
+    field (390625 blocks of 64) at its widest plane count, 24."""
+    from repro.core import device_encode as de
+
+    nblk = HURRICANE[0] * HURRICANE[1] * HURRICANE[2] // 64
+    p2b = de._zfp_pass2b.lower(
+        jax.ShapeDtypeStruct((nblk, 64), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((nblk, 64), jnp.bool_, sharding=one_chip),
+        jax.ShapeDtypeStruct((nblk,), jnp.int32, sharding=one_chip),
+        n_words=1 << 23, n_planes=24,
     ).compile()
-    mem = p2.memory_analysis()
-    total = mem.temp_size_in_bytes + mem.argument_size_in_bytes + mem.output_size_in_bytes
-    assert total < 12 * 2**30, total
+    _fits_one_chip(p2b)
